@@ -36,9 +36,12 @@ def main():
         "key_types": N_UNI + N_BI,
         "features_per_packet": N_FEATURES,
         "lambdas": list(LAMBDAS),
-        "note": "16 MiB VMEM/core fits ~260k slots/key-type resident "
-                "(4 atoms x 4 decays x f32); Tofino comparison: the paper "
-                "uses 100% of TNA pipe-0 stages and 37% SRAM (Table 3)",
+        "note": "the Pallas FC kernel holds its lane-packed tables "
+                "resident, 512 B per slot (4 MiB at 8192 slots), and "
+                "requests 2x that plus its statistics blocks plus 4 MiB "
+                "of VMEM (13 MiB at 8192 slots; see DESIGN.md §2); "
+                "Tofino comparison: the paper uses 100% of TNA pipe-0 "
+                "stages and 37% SRAM (Table 3)",
     }
     print("feature_update VMEM @8192 slots/key:",
           kernel["feature_update_vmem_per_keytype_bytes"] / 2**20, "MiB")

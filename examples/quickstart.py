@@ -18,6 +18,9 @@ bit-identical to one-batch for the serial-semantics FC backends).
 from repro.detection.metrics import auc
 from repro.serving import DetectionService
 from repro.traffic import synth_trace
+from repro.launch.cache import enable_compile_cache
+
+enable_compile_cache()
 
 # 1. a trace: benign training prefix + eval window with the attack mixed in
 data = synth_trace("mirai", n_train=12000, n_benign_eval=6000,

@@ -16,8 +16,8 @@ Backends (all emit the identical (n, N_FEATURES) layout):
     O(log n) depth over a packet batch.  Exact mode only.
   * ``pallas`` — the full-feature Pallas kernel
     (kernels/feature_update.feature_update_full): the switch pipeline on a
-    TPU core, flow tables resident in VMEM.  Exact mode only; runs in
-    interpret mode on CPU and compiles on real TPU.
+    TPU core, flow tables resident in VMEM.  Exact mode only; interpreted
+    when lowered for the CPU, compiled on TPU.
   * ``sharded`` — hash-partitioned flow tables (core/sharded.py): S shards
     executed in parallel (vmap / mesh placement via the ``flow_shards``
     logical axis), bit-identical to ``serial`` in both modes.  Select the
@@ -96,10 +96,10 @@ def _scan(state, pkts, mode: str = "exact", **_kw):
 
 
 @register_backend("pallas")
-def _pallas(state, pkts, mode: str = "exact", chunk: int = 256,
-            interpret=None, **_kw):
+def _pallas(state, pkts, mode: str = "exact", chunk=None, interpret=None,
+            **_kw):
     from repro.kernels import ops
-    return ops.feature_update_full(state, pkts, chunk=chunk,
+    return ops.feature_update_full(state, pkts, chunk=chunk or ops.BLOCK,
                                    interpret=interpret)
 
 

@@ -288,7 +288,7 @@ def process_sketch(state: Dict, pkts: Dict[str, jax.Array]
 # ---------------------------------------------------------------------------
 def compute_features_sketch(state: Dict, pkts: Dict[str, jax.Array],
                             mode: str = "exact", fc_backend: str = "scan",
-                            chunk: int = 256, interpret=None,
+                            chunk=None, interpret=None,
                             **_kw) -> Tuple[Dict, jax.Array]:
     """Route a sketch-state batch to an implementation: ``pallas`` → the
     row-update kernel, anything else → the pure-JAX reference.  Partition
@@ -300,8 +300,8 @@ def compute_features_sketch(state: Dict, pkts: Dict[str, jax.Array],
                          "round-robin decay is tied to the dense rr "
                          "counters)")
     if fc_backend == "pallas":
-        from repro.kernels.ops import sketch_update_full
-        return sketch_update_full(state, pkts, chunk=chunk,
+        from repro.kernels.ops import BLOCK, sketch_update_full
+        return sketch_update_full(state, pkts, chunk=chunk or BLOCK,
                                   interpret=interpret)
     return process_sketch(state, pkts)
 

@@ -15,9 +15,9 @@ Backends (all emit identical per-record anomaly scores, ≤1e-5 apart):
   * ``pallas`` — the fused ensemble kernel (kernels/kitnet_ae.py):
     gather + normalise on the host graph, then one ``pallas_call`` grid of
     (AE, batch-tile) steps — two MXU matmuls + sigmoids + masked RMSE per
-    step, the reconstruction never materialised in HBM.  Runs in interpret
-    mode on CPU; ``REPRO_PALLAS_COMPILE=1`` compiles it on TPU (read per
-    call, ``interpret=`` wins — same plumbing as the FC kernels).
+    step, the reconstruction never materialised in HBM.  Interpreted when
+    lowered for the CPU, compiled on TPU (``repro.kernels.run_pallas``;
+    ``interpret=`` forces either — same plumbing as the FC kernels).
 
 Each registered backend supplies the *ensemble* stage
 ``fn(params, idx, mask, xn) -> (B, k) RMSE`` plus a full scoring function;
@@ -118,7 +118,7 @@ def _ensemble_pallas(params, idx, mask, xn, *, bb: int = 128, interpret=None,
 
 @functools.partial(jax.jit, static_argnames=("bb", "interpret"))
 def _score_pallas_jit(params, idx, mask, lo, hi, r_lo, r_hi, X, *,
-                      bb: int, interpret: bool):
+                      bb: int, interpret):
     from repro.detection.kitnet import _normalize, output_rmse
     from repro.kernels.kitnet_ae import kitnet_ensemble
     xn = _normalize(X, lo, hi)
@@ -131,11 +131,7 @@ def _score_pallas_jit(params, idx, mask, lo, hi, r_lo, r_hi, X, *,
 
 
 def _score_pallas(net, X, *, bb: int = 128, interpret=None, **_kw):
-    # one jit over the whole scoring path (like the einsum _score) —
-    # interpret is resolved from the environment HERE, per call, so it can
-    # be a static jit arg without freezing REPRO_PALLAS_COMPILE at import
-    from repro.kernels.ops import interpret_default
-    interpret = interpret_default() if interpret is None else interpret
+    # one jit over the whole scoring path (like the einsum _score)
     return _score_pallas_jit(net.params, net.idx, net.mask, net.norm_min,
                              net.norm_max, net.out_min, net.out_max, X,
                              bb=bb, interpret=interpret)

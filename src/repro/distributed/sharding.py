@@ -7,11 +7,12 @@ so the exact same model code runs on 1 CPU device and on a 512-chip mesh.
 from __future__ import annotations
 
 import contextlib
+import math
 import threading
 from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 Axis = Union[None, str, Tuple[str, ...]]
 
@@ -40,42 +41,16 @@ PRODUCTION_RULES: Dict[str, Axis] = {
 }
 
 
-def set_mesh(mesh):
-    """``jax.set_mesh`` across jax versions.
-
-    Newer jax exposes ``jax.set_mesh`` as the context manager binding the
-    ambient mesh; on older releases (<= 0.4.x) ``jax.sharding.Mesh`` itself
-    is the context manager providing the resource environment that lets
-    ``jax.jit`` resolve bare PartitionSpecs.  Call sites use this shim so
-    the tier-1 suite runs on both.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def ambient_mesh():
-    """The physical mesh bound by :func:`set_mesh`, or ``None``.
+    """The (abstract) mesh bound by ``jax.set_mesh``, or ``None``.
 
-    Works across jax versions: newer releases track the ambient mesh on the
-    jax side (``jax.set_mesh``), older ones (<= 0.4.x) stash the ``with
-    Mesh(...)`` resource environment in ``thread_resources``.  Callers that
-    need an explicit ``Mesh`` object (e.g. ``shard_map`` in
-    ``core/bucketed.py``) use this instead of threading one by hand.
+    Abstract, so it resolves the same inside ``jax.jit`` (the fused step
+    resolves placement at trace time) as outside; ``shard_map`` and
+    ``NamedSharding`` constraints take it directly.  Callers use this
+    instead of threading a mesh by hand (e.g. ``core/bucketed.py``).
     """
-    try:
-        m = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:  # newer jax: the ambient concrete mesh, when one is set
-        m = jax.sharding.get_mesh()
-        if m is not None and getattr(m, "axis_names", ()):
-            return m
-    except Exception:
-        pass
-    return None
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def _rule_binding(name: str):
@@ -131,13 +106,9 @@ class ShardContext:
     def wrap(self, fn):
         """Run ``fn`` under ``shard_map`` with every input/output's leading
         (chunk) axis split over the bound mesh axes."""
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:  # pragma: no cover - jax >= 0.6 spelling
-            from jax import shard_map
         spec = P(self.binding)
-        return shard_map(fn, mesh=self.mesh, in_specs=spec, out_specs=spec,
-                         check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=spec,
+                             out_specs=spec, check_vma=False)
 
     def gather_tails(self, t: jax.Array) -> jax.Array:
         """All-gather per-chunk tail summaries across shards: local
@@ -152,6 +123,18 @@ class ShardContext:
         for a in self.axes:
             idx = idx * self.mesh.shape[a] + jax.lax.axis_index(a)
         return jax.lax.dynamic_slice_in_dim(x, idx * n_local, n_local, 0)
+
+
+def auto_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """``jax.make_mesh`` with ``Auto`` axes: the compiler propagates
+    shardings and ``with_sharding_constraint`` is a placement hint.
+    (``jax.make_mesh`` defaults to ``Explicit`` axes, under which every
+    op on a sharded operand must resolve its output sharding and a
+    constraint asserts instead of placing.)"""
+    n = math.prod(shape)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=jax.devices()[:n])
 
 
 @contextlib.contextmanager
@@ -169,31 +152,13 @@ def flow_mesh(n_devices: Optional[int] = None, axis: str = "data",
     on a real accelerator mesh the same call binds physical devices.
     """
     n = jax.device_count() if n_devices is None else int(n_devices)
-    mesh = jax.make_mesh((n,), (axis,))
+    mesh = auto_mesh((n,), (axis,))
     with contextlib.ExitStack() as es:
-        es.enter_context(set_mesh(mesh))
+        es.enter_context(jax.set_mesh(mesh))
         es.enter_context(use_rules(
             {"flow_shards": axis, "tenants": axis} if rules is None
             else rules))
         yield mesh
-
-
-def named_shardings(mesh, tree):
-    """PartitionSpec/None leaves -> ``NamedSharding`` on ``mesh``.
-
-    Older jax's ``jax.jit`` rejects bare PartitionSpecs in
-    ``in_shardings``/``out_shardings``; newer jax resolves them against the
-    ambient mesh.  Converting explicitly works on both.  ``None`` leaves
-    (and ``None`` tree prefixes) keep their "unspecified — let the compiler
-    propagate" meaning and pass through untouched.
-    """
-    from jax.sharding import NamedSharding
-
-    def conv(x):
-        return NamedSharding(mesh, x) if isinstance(x, P) else x
-
-    return jax.tree_util.tree_map(
-        conv, tree, is_leaf=lambda x: x is None or isinstance(x, P))
 
 
 class AxisRules:

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import run_pallas
+
 NEG_INF = -1e30
 
 
@@ -75,7 +77,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                               "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, bq: int = 128, bk: int = 128,
-                    interpret: bool = True):
+                    interpret=None):
     """q: (B, H, Sq, D); k/v: (B, K, Sk, D) with H a multiple of K.
 
     Returns (B, H, Sq, D) in q.dtype.
@@ -101,7 +103,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, bq=bq, bk=bk, seq_kv=Sk)
-    out = pl.pallas_call(
+    make = lambda interp: pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
         in_specs=[
@@ -116,6 +118,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        interpret=interpret,
-    )(qf, kf, vf)
+        interpret=interp,
+    )
+    out = run_pallas(make, qf, kf, vf, interpret=interpret)
     return out.reshape(B, H, Sq_p, D)[:, :, :Sq]

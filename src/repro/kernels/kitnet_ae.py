@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import run_pallas
+
 
 def _ae_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, mask_ref, out_ref):
     x = x_ref[0].astype(jnp.float32)                     # (bB, m)
@@ -36,7 +38,7 @@ def _ae_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, mask_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("bb", "interpret"))
 def kitnet_ensemble(x_sub, w1, b1, w2, b2, mask, *, bb: int = 128,
-                    interpret: bool = True):
+                    interpret=None):
     """x_sub: (B, k, m) gathered+normalised feature subsets.
     w1 (k,m,h), b1 (k,h), w2 (k,h,m), b2 (k,m), mask (k,m).
     Returns per-AE RMSE (B, k).
@@ -50,7 +52,7 @@ def kitnet_ensemble(x_sub, w1, b1, w2, b2, mask, *, bb: int = 128,
         x_sub = jnp.pad(x_sub, ((0, Bp - B), (0, 0), (0, 0)))
     xk = x_sub.transpose(1, 0, 2)                        # (k, Bp, m)
 
-    out = pl.pallas_call(
+    make = lambda interp: pl.pallas_call(
         _ae_kernel,
         grid=(k, nb),
         in_specs=[
@@ -63,6 +65,9 @@ def kitnet_ensemble(x_sub, w1, b1, w2, b2, mask, *, bb: int = 128,
         ],
         out_specs=pl.BlockSpec((1, bb, 1), lambda e, b: (e, b, 0)),
         out_shape=jax.ShapeDtypeStruct((k, Bp, 1), jnp.float32),
-        interpret=interpret,
-    )(xk, w1, b1[:, None, :], w2, b2[:, None, :], mask[:, None, :])
+        interpret=interp,
+        name="kitnet_ensemble",
+    )
+    out = run_pallas(make, xk, w1, b1[:, None, :], w2, b2[:, None, :],
+                     mask[:, None, :], interpret=interpret)
     return out[:, :B, 0].T                               # (B, k)

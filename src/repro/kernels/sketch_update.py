@@ -1,8 +1,8 @@
 """Count-Min sketch flow-state update as a Pallas kernel.
 
-The sketch analogue of ``feature_update._fc_full_kernel``: one grid step
-processes a chunk of packets with ALL sketch tables resident in VMEM; an
-in-kernel ``fori_loop`` applies, per packet and per key type:
+The sketch analogue of ``feature_update._fc_full_kernel``: grid steps walk
+blocks of packets with ALL sketch tables resident in VMEM; an in-kernel
+``fori_loop`` applies, per packet and per key type:
 
     hash rows (host-precomputed indices) -> gather the R hashed cells
     -> decay to now -> per-atom min across rows (the Count-Min read)
@@ -10,12 +10,14 @@ in-kernel ``fori_loop`` applies, per packet and per key type:
        its own decayed value) -> statistics -> scatter the R cells back
 
 Table layout mirrors the dense full kernel's flattening: the sketch's
-(key, row, width[, dir]) axes collapse into one row axis so every access
-is a ``pl.ds(row, 1)`` dynamic slice on a (rows_total, N_DECAY) ref —
-uni atoms ``(N_UNI·R·W, ND)``, direction-paired bi atoms
-``(N_BI·R·W·2, ND)``, channel SR state ``(N_BI·R·W, ND)``.  Row indices
-are precomputed host-side (vectorised hashing), so the kernel never
-hashes; ``evict_age`` rides along as a (1, 1) scalar ref.
+(key, row, width[, dir]) axes collapse into one row axis — uni atoms
+``(N_UNI·R·W, ND)``, direction-paired bi atoms ``(N_BI·R·W·2, ND)``,
+channel SR state ``(N_BI·R·W, ND)`` — and each table is lane-packed to
+``(rows / 32, 128)`` exactly like the dense kernel's
+(``feature_update.get_row`` / ``put_row``).  Row indices are precomputed
+host-side (vectorised hashing) and arrive in SMEM, so the kernel never
+hashes; ``evict_age`` rides along as a lane-broadcast row of the
+constant block (``feature_update.const_rows``).
 
 The R-row loop is STATICALLY unrolled (R is a shape constant, typically
 2-8), so on TPU each packet costs R dynamic-slice gathers + a vector
@@ -32,32 +34,28 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.state import LAMBDAS, N_BI, N_DECAY, N_FEATURES, N_UNI
-from repro.kernels.feature_update import _BLOCKED_TO_ORACLE, _safe_div
+from repro.core.state import N_BI, N_DECAY, N_FEATURES, N_UNI
+from repro.kernels.feature_update import (
+    BLOCK, _BLOCKED_TO_ORACLE, _safe_div, const_rows, copy_tables_in,
+    decay_update, get_row, pack_lanes, pack_rows, pad_packets, put_row,
+    stats_of, table_call, unpack_rows,
+)
 
-_LAM = tuple(LAMBDAS)
+_TABLES = ("ult", "uw", "uls", "uss", "blt", "bw", "bls", "bss",
+           "brl", "bsr", "bslt", "bsw")
 
 
-def _sketch_kernel(lam_ref, age_ref,
-                   urow_ref, brow_o_ref, brow_p_ref, brow_s_ref,
-                   ts_ref, len_ref,
-                   ult_i, uw_i, uls_i, uss_i,
-                   blt_i, bw_i, bls_i, bss_i, brl_i, bsr_i, bslt_i, bsw_i,
-                   ult, uw, uls, uss,
-                   blt, bw, bls, bss, brl, bsr, bslt, bsw,
-                   stats_ref, *, chunk: int, n_pkts: int, rows: int):
+def _sketch_kernel(const_ref, idx_ref, ts_ref, len_ref, *refs,
+                   chunk: int, n_pkts: int, rows: int):
+    n_tab = len(_TABLES)
+    copy_tables_in(refs[:n_tab], refs[n_tab:2 * n_tab])
+    ult, uw, uls, uss, blt, bw, bls, bss, brl, bsr, bslt, bsw = \
+        refs[n_tab:2 * n_tab]
+    stats_ref = refs[2 * n_tab]
     step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _copy_in():
-        for src, dst in ((ult_i, ult), (uw_i, uw), (uls_i, uls), (uss_i, uss),
-                         (blt_i, blt), (bw_i, bw), (bls_i, bls), (bss_i, bss),
-                         (brl_i, brl), (bsr_i, bsr), (bslt_i, bslt),
-                         (bsw_i, bsw)):
-            dst[...] = src[...]
-
-    lam = lam_ref[...]                                  # (1, N_DECAY)
-    age = age_ref[0, 0]
+    lam = const_ref[pl.ds(0, 1), :]                     # (1, 128)
+    age = const_ref[pl.ds(1, 1), :]                     # (1, 128)
+    n_idx = (N_UNI + N_BI) * rows
 
     def _minimum(vals):
         m = vals[0]
@@ -70,16 +68,19 @@ def _sketch_kernel(lam_ref, age_ref,
         update.  Returns (per-row updated atoms, per-atom estimates)."""
         cand = {"w": [], "ls": [], "ss": []}
         for row in rws:
-            lt = lt_tab[pl.ds(row, 1), :]
+            lt = get_row(lt_tab, row)
             dt = jnp.maximum(t - lt, 0.0)
             dead = (lt < 0.0) | ((age > 0.0) & (dt > age))
-            delta = jnp.where(dead, 0.0, jnp.exp2(-lam * dt))
-            # candidate-only formulation (see core/sketch._cu_update):
-            # a second use of the raw product v·δ would block the fma
+            # decay_update with the dead mask standing in for "fresh";
+            # candidate-only formulation (see core/sketch._cu_update): a
+            # second use of the raw product v·δ would block the fma
             # contraction the serial oracle's expression gets
-            cand["w"].append(w_tab[pl.ds(row, 1), :] * delta + 1.0)
-            cand["ls"].append(ls_tab[pl.ds(row, 1), :] * delta + x)
-            cand["ss"].append(ss_tab[pl.ds(row, 1), :] * delta + x * x)
+            w2, ls2, ss2 = decay_update(
+                lam, jnp.where(dead, -1.0, lt), get_row(w_tab, row),
+                get_row(ls_tab, row), get_row(ss_tab, row), t, x)
+            cand["w"].append(w2)
+            cand["ls"].append(ls2)
+            cand["ss"].append(ss2)
         ew, els, ess = (_minimum(cand[k]) for k in ("w", "ls", "ss"))
         upd = [(jnp.maximum(cand["w"][r] - 1.0, ew),
                 jnp.maximum(cand["ls"][r] - x, els),
@@ -87,70 +88,65 @@ def _sketch_kernel(lam_ref, age_ref,
                for r in range(rows)]
         return upd, (ew, els, ess)
 
-    def _stats(w, ls, ss):
-        mu = _safe_div(ls, w)
-        var = jnp.abs(_safe_div(ss, w) - mu * mu)
-        return mu, var, jnp.sqrt(var)
-
     def body(i, _):
-        g = step * chunk + i
-        valid = g < n_pkts
+        valid = step * chunk + i < n_pkts
         t = ts_ref[i]
         x = len_ref[i]
+        base = i * n_idx
         pieces = []
 
         # ---- unidirectional key types ----
         for ki in range(N_UNI):
-            rws = [urow_ref[i, ki * rows + r] for r in range(rows)]
+            rws = [idx_ref[base + ki * rows + r] for r in range(rows)]
             upd, (ew, els, ess) = _cu(ult, uw, uls, uss, rws, t, x)
-            mu, var, sig = _stats(ew, els, ess)
+            mu, var, sig = stats_of(ew, els, ess)
             pieces += [ew, mu, sig]
-            for r, row in enumerate(rws):
-                w2, ls2, ss2 = upd[r]
 
-                @pl.when(valid)
-                def _store_uni(row=row, w2=w2, ls2=ls2, ss2=ss2):
-                    ult[pl.ds(row, 1), :] = jnp.full_like(w2, t)
-                    uw[pl.ds(row, 1), :] = w2
-                    uls[pl.ds(row, 1), :] = ls2
-                    uss[pl.ds(row, 1), :] = ss2
+            @pl.when(valid)
+            def _store_uni():
+                for row, (w2, ls2, ss2) in zip(rws, upd):
+                    put_row(ult, row, jnp.full_like(w2, t))
+                    put_row(uw, row, w2)
+                    put_row(uls, row, ls2)
+                    put_row(uss, row, ss2)
 
         # ---- bidirectional key types ----
         for ki in range(N_BI):
-            orws = [brow_o_ref[i, ki * rows + r] for r in range(rows)]
-            prws = [brow_p_ref[i, ki * rows + r] for r in range(rows)]
-            srws = [brow_s_ref[i, ki * rows + r] for r in range(rows)]
+            off = base + (N_UNI + ki) * rows
+            orws = [idx_ref[off + r] for r in range(rows)]
+            prws = [o ^ 1 for o in orws]                # opposite direction
+            srws = [o >> 1 for o in orws]               # channel (SR) row
 
             upd, (ew_o, els_o, ess_o) = _cu(blt, bw, bls, bss, orws, t, x)
-            mu_o, var_o, sig_o = _stats(ew_o, els_o, ess_o)
+            mu_o, var_o, sig_o = stats_of(ew_o, els_o, ess_o)
 
             # stale opposite-direction stats: stored values, aged cells
             # read as empty, Count-Min min across rows
             wp, lsp, ssp = [], [], []
             for prow in prws:
-                lt_p = blt[pl.ds(prow, 1), :]
+                lt_p = get_row(blt, prow)
                 zap = (age > 0.0) & ((t - lt_p) > age)
-                z = lambda tab: jnp.where(zap, 0.0, tab[pl.ds(prow, 1), :])
+                z = lambda tab: jnp.where(zap, 0.0, get_row(tab, prow))
                 wp.append(z(bw))
                 lsp.append(z(bls))
                 ssp.append(z(bss))
             w_p, ls_p, ss_p = _minimum(wp), _minimum(lsp), _minimum(ssp)
-            mu_p, var_p, sig_p = _stats(w_p, ls_p, ss_p)
+            mu_p, var_p, sig_p = stats_of(w_p, ls_p, ss_p)
 
             # SR per row; emit the row with the least conservative
             # channel count (running strict-< select == first argmin)
             r_res = x - mu_o
             sr2s, sw2s = [], []
             for prow, srow in zip(prws, srws):
-                sr = bsr[pl.ds(srow, 1), :]
-                sr_lt = bslt[pl.ds(srow, 1), :]
+                sr = get_row(bsr, srow)
+                sr_lt = get_row(bslt, srow)
                 dt_sr = jnp.maximum(t - sr_lt, 0.0)
                 evict = (age > 0.0) & (dt_sr > age)
                 dsr = jnp.where((sr_lt < 0.0) | evict, 0.0,
                                 jnp.exp2(-lam * dt_sr))
-                r_opp = jnp.where(evict, 0.0, brl[pl.ds(prow, 1), :])
+                r_opp = jnp.where(evict, 0.0, get_row(brl, prow))
                 sr2s.append(sr * dsr + r_res * r_opp)
-                sw2s.append(bsw[pl.ds(srow, 1), :] * dsr)
+                sw2s.append(get_row(bsw, srow) * dsr)
             m_sw = _minimum(sw2s)
             sw2s = [jnp.maximum(v, m_sw + 1.0) for v in sw2s]
             sr_sel, sw_min = sr2s[0], sw2s[0]
@@ -165,24 +161,21 @@ def _sketch_kernel(lam_ref, age_ref,
             pcc = _safe_div(cov, sig_o * sig_p)
             pieces += [ew_o, mu_o, sig_o, mag, rad, cov, pcc]
 
-            for r in range(rows):
-                orow, srow = orws[r], srws[r]
-                w2, ls2, ss2 = upd[r]
-                sr2, sw2 = sr2s[r], sw2s[r]
+            @pl.when(valid)
+            def _store_bi():
+                for r in range(rows):
+                    orow, srow = orws[r], srws[r]
+                    w2, ls2, ss2 = upd[r]
+                    put_row(blt, orow, jnp.full_like(w2, t))
+                    put_row(bw, orow, w2)
+                    put_row(bls, orow, ls2)
+                    put_row(bss, orow, ss2)
+                    put_row(brl, orow, r_res)
+                    put_row(bsr, srow, sr2s[r])
+                    put_row(bslt, srow, jnp.full_like(w2, t))
+                    put_row(bsw, srow, sw2s[r])
 
-                @pl.when(valid)
-                def _store_bi(orow=orow, srow=srow, w2=w2, ls2=ls2,
-                              ss2=ss2, sr2=sr2, sw2=sw2):
-                    blt[pl.ds(orow, 1), :] = jnp.full_like(w2, t)
-                    bw[pl.ds(orow, 1), :] = w2
-                    bls[pl.ds(orow, 1), :] = ls2
-                    bss[pl.ds(orow, 1), :] = ss2
-                    brl[pl.ds(orow, 1), :] = r_res
-                    bsr[pl.ds(srow, 1), :] = sr2
-                    bslt[pl.ds(srow, 1), :] = jnp.full_like(w2, t)
-                    bsw[pl.ds(srow, 1), :] = sw2
-
-        row_stats = jnp.concatenate(pieces, axis=-1)    # (1, N_FEATURES)
+        row_stats = pack_lanes(pieces)                  # (1, 128)
 
         @pl.when(valid)
         def _store_stats():
@@ -195,53 +188,19 @@ def _sketch_kernel(lam_ref, age_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "interpret", "n", "rows"))
-def _sketch_call(tables, age, urow, brow_o, brow_p, brow_s, ts, lens, *,
-                 chunk: int, interpret: bool, n: int, rows: int):
-    n_pad = urow.shape[0]
-    nc = n_pad // chunk
-    rows_u = tables["ult"].shape[0]
-    rows_b = tables["blt"].shape[0]
-    rows_s = tables["bsr"].shape[0]
-
+def _sketch_call(tables, age, idx, ts, lens, *, chunk: int, interpret,
+                 n: int, rows: int):
     kernel = functools.partial(_sketch_kernel, chunk=chunk, n_pkts=n,
                                rows=rows)
-    spec_u = pl.BlockSpec((rows_u, N_DECAY), lambda s: (0, 0))
-    spec_b = pl.BlockSpec((rows_b, N_DECAY), lambda s: (0, 0))
-    spec_s = pl.BlockSpec((rows_s, N_DECAY), lambda s: (0, 0))
-    spec_idx = pl.BlockSpec((chunk, 2 * rows), lambda s: (s, 0))
-    spec_pkt = pl.BlockSpec((chunk,), lambda s: (s,))
-    tab_specs = [spec_u] * 4 + [spec_b] * 5 + [spec_s] * 3
-    tab_shapes = ([jax.ShapeDtypeStruct((rows_u, N_DECAY), jnp.float32)] * 4 +
-                  [jax.ShapeDtypeStruct((rows_b, N_DECAY), jnp.float32)] * 5 +
-                  [jax.ShapeDtypeStruct((rows_s, N_DECAY), jnp.float32)] * 3)
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(nc,),
-        in_specs=[pl.BlockSpec((1, N_DECAY), lambda s: (0, 0)),
-                  pl.BlockSpec((1, 1), lambda s: (0, 0)),
-                  spec_idx, spec_idx, spec_idx, spec_idx,
-                  spec_pkt, spec_pkt] + tab_specs,
-        out_specs=tab_specs + [
-            pl.BlockSpec((chunk, N_FEATURES), lambda s: (s, 0))],
-        out_shape=tab_shapes + [
-            jax.ShapeDtypeStruct((n_pad, N_FEATURES), jnp.float32)],
-        input_output_aliases={8 + k: k for k in range(12)},
-        interpret=interpret,
-    )(jnp.asarray(_LAM, jnp.float32)[None, :],
-      age.reshape(1, 1).astype(jnp.float32),
-      urow, brow_o, brow_p, brow_s, ts, lens,
-      tables["ult"], tables["uw"], tables["uls"], tables["uss"],
-      tables["blt"], tables["bw"], tables["bls"], tables["bss"],
-      tables["brl"], tables["bsr"], tables["bslt"], tables["bsw"])
-    stats = out[-1][:n]
-    names = ("ult", "uw", "uls", "uss", "blt", "bw", "bls", "bss",
-             "brl", "bsr", "bslt", "bsw")
-    return dict(zip(names, out[:-1])), stats
+    tabs, stats = table_call(
+        kernel, [pack_rows(tables[k]) for k in _TABLES], [idx, ts, lens],
+        ts.shape[0], chunk, interpret, consts=const_rows(age))
+    new = {k: unpack_rows(v, tables[k].shape[0])
+           for k, v in zip(_TABLES, tabs)}
+    return new, stats[:n, :N_FEATURES]
 
 
-def sketch_update_full(state, pkts, *, chunk: int = 256,
-                       interpret: bool = True):
+def sketch_update_full(state, pkts, *, chunk: int = BLOCK, interpret=None):
     """Full sketch-state FC (all 80 features) as one Pallas pipeline.
 
     state: an ``init_state(..., state_backend="sketch")`` dict.  Returns
@@ -258,40 +217,29 @@ def sketch_update_full(state, pkts, *, chunk: int = 256,
     n = ts.shape[0]
 
     # host-side flattened row precomputation: uni row (k·R+r)·W + col,
-    # bi-direction row (…)·2 + d, channel row (k·R+r)·W + col
+    # own-direction bi row ((k·R+r)·W + col)·2 + d; the kernel derives the
+    # opposite-direction row (``^ 1``) and the channel row (``>> 1``)
     key_off = (jnp.arange(N_UNI, dtype=jnp.int32) * R)[:, None] \
         + jnp.arange(R, dtype=jnp.int32)[None, :]               # (K, R)
     ucols = jnp.stack([sl["src_mac_ip"], sl["src_ip"]], 1)      # (n, K, R)
     urow = (key_off[None] * W + ucols).reshape(n, -1)
     bcols = jnp.stack([sl["channel"], sl["socket"]], 1)
     bbase = (key_off[None] * W + bcols).reshape(n, -1)          # (n, K·R)
-    d = sl["dir"][:, None]
-    brow_o = bbase * 2 + d
-    brow_p = bbase * 2 + (1 - d)
-    brow_s = bbase
-
-    nc = -(-max(n, 1) // chunk)
-    n_pad = nc * chunk
-    pad2 = lambda a: jnp.pad(a, ((0, n_pad - n), (0, 0)))
+    brow_o = bbase * 2 + sl["dir"][:, None]
+    n_pad = pad_packets(n, chunk)
+    idx = jnp.pad(jnp.concatenate([urow, brow_o], -1).astype(jnp.int32),
+                  ((0, n_pad - n), (0, 0))).reshape(-1)
     pad1 = lambda a: jnp.pad(a, (0, n_pad - n))
     uni, bi = state["uni"], state["bi"]
     tables = {
-        "ult": uni["last_t"].reshape(-1, N_DECAY),
-        "uw": uni["w"].reshape(-1, N_DECAY),
-        "uls": uni["ls"].reshape(-1, N_DECAY),
-        "uss": uni["ss"].reshape(-1, N_DECAY),
-        "blt": bi["last_t"].reshape(-1, N_DECAY),
-        "bw": bi["w"].reshape(-1, N_DECAY),
-        "bls": bi["ls"].reshape(-1, N_DECAY),
-        "bss": bi["ss"].reshape(-1, N_DECAY),
-        "brl": bi["res_last"].reshape(-1, N_DECAY),
-        "bsr": bi["sr"].reshape(-1, N_DECAY),
-        "bslt": bi["sr_last_t"].reshape(-1, N_DECAY),
-        "bsw": bi["sw"].reshape(-1, N_DECAY),
+        "ult": uni["last_t"], "uw": uni["w"], "uls": uni["ls"],
+        "uss": uni["ss"], "blt": bi["last_t"], "bw": bi["w"],
+        "bls": bi["ls"], "bss": bi["ss"], "brl": bi["res_last"],
+        "bsr": bi["sr"], "bslt": bi["sr_last_t"], "bsw": bi["sw"],
     }
+    tables = {k: v.reshape(-1, N_DECAY) for k, v in tables.items()}
     new_tab, stats = _sketch_call(
-        tables, state["evict_age"], pad2(urow), pad2(brow_o), pad2(brow_p),
-        pad2(brow_s), pad1(ts), pad1(lens), chunk=chunk,
+        tables, state["evict_age"], idx, pad1(ts), pad1(lens), chunk=chunk,
         interpret=interpret, n=n, rows=R)
 
     feats = jnp.take(stats, jnp.asarray(_BLOCKED_TO_ORACLE), axis=1)

@@ -35,8 +35,7 @@ from repro.distributed.flags import use_scan_unroll
 from repro.distributed.mesh_rules import make_rules
 from repro.distributed.params import (batch_specs, cache_specs, opt_specs,
                                       param_specs)
-from repro.distributed.sharding import (AxisRules, named_shardings, set_mesh,
-                                        use_rules)
+from repro.distributed.sharding import AxisRules, use_rules
 from repro.launch.mesh import make_production_mesh, mesh_shape_dict
 from repro.launch.specs import arch_for_cell, input_specs, train_config_for, use_fsdp
 
@@ -177,11 +176,11 @@ def _lower_once(arch: str, shape_name: str, multi_pod: bool, cfg_in,
         dp_axes = tuple(a for a in mesh.axis_names if a != "model")
         moe_ctx = (_flags.use_local_moe_dispatch(mesh, dp_axes, "model")
                    if moe_local else contextlib.nullcontext())
-        with use_scan_unroll(unroll), moe_ctx, set_mesh(mesh):
+        with use_scan_unroll(unroll), moe_ctx, jax.set_mesh(mesh):
             jitted = jax.jit(
                 step,
-                in_shardings=named_shardings(mesh, in_shardings),
-                out_shardings=named_shardings(mesh, out_shardings))
+                in_shardings=in_shardings,
+                out_shardings=out_shardings)
             lowered = jitted.lower(*args)
             compiled = lowered.compile()
             mem = compiled.memory_analysis()

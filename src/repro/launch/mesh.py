@@ -6,18 +6,18 @@ dry-run needs to set XLA_FLAGS before that happens.
 """
 from __future__ import annotations
 
-import jax
+from repro.distributed.sharding import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(n_data: int = 2, n_model: int = 4):
     """Small host-device mesh for tests (requires XLA host-device flag)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def mesh_shape_dict(mesh) -> dict:

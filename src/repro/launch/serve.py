@@ -14,7 +14,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch, reduced as reduce_cfg
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
+
+
+def _device() -> str:
+    d = jax.devices()[0]
+    return f"{d.platform}/{d.device_kind}"
 
 
 def serve_detect(args):
@@ -39,7 +45,8 @@ def serve_detect(args):
     dt = time.time() - t0
     labels = data["eval"]["label"][idx - eval_start]
     n = len(data["eval"]["ts"])
-    print(f"processed {n} pkts in {dt:.1f}s ({n / dt:.0f} pps on-CPU), "
+    print(f"processed {n} pkts in {dt:.1f}s ({n / dt:.0f} pps on "
+          f"{_device()}), "
           f"{len(scores)} records, {int(alarms.sum())} alarms, "
           f"AUC={auc(scores, labels):.3f}")
 
@@ -60,7 +67,7 @@ def serve_lm(args):
     dt = time.time() - t0
     toks = sum(len(v) for v in outputs.values())
     print(f"served {len(outputs)} requests, {toks} tokens in {dt:.1f}s "
-          f"({toks / dt:.1f} tok/s on-CPU)")
+          f"({toks / dt:.1f} tok/s on {_device()})")
 
 
 def main():
@@ -77,6 +84,7 @@ def main():
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "detect":
         serve_detect(args)
     else:

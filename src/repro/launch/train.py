@@ -21,8 +21,7 @@ from repro.configs.base import ShapeConfig
 from repro.data import Prefetcher, lm_batches
 from repro.distributed.mesh_rules import make_rules
 from repro.distributed.params import batch_specs, opt_specs, param_specs
-from repro.distributed.sharding import (AxisRules, named_shardings, set_mesh,
-                                        use_rules)
+from repro.distributed.sharding import AxisRules, auto_mesh, use_rules
 from repro.models import build_model
 from repro.training import CheckpointManager, init_train_state, make_train_step
 from repro.training.fault import StragglerMonitor, resilient_loop
@@ -55,7 +54,7 @@ def main():
     rules_d = None
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = auto_mesh((d, m), ("data", "model"))
         shp = ShapeConfig("cli", args.seq, args.batch, "train")
         rules_d = make_rules(cfg, shp, multi_pod=False, model_size=m,
                              dp_size=d)
@@ -75,8 +74,8 @@ def main():
                                               "train"), rules)
             step_fn = jax.jit(
                 step_fn,
-                in_shardings=named_shardings(mesh, (ss, bs)),
-                out_shardings=named_shardings(mesh, (ss, None)))
+                in_shardings=(ss, bs),
+                out_shardings=(ss, None))
         else:
             step_fn = jax.jit(step_fn)
 
@@ -97,7 +96,7 @@ def main():
               f"tokens/s={toks / dt:.0f}")
 
     if mesh is not None:
-        with use_rules(rules_d), set_mesh(mesh):
+        with use_rules(rules_d), jax.set_mesh(mesh):
             run()
     else:
         run()

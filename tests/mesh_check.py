@@ -112,7 +112,7 @@ def battery_ambient():
     assert _resolve_placement(BUCKETS) == (None, None)
     with flow_mesh(N_DEVICES) as mesh:
         m = ambient_mesh()
-        assert m is not None and m.devices.size == N_DEVICES, m
+        assert m is not None and m.size == N_DEVICES, m
         assert flow_shards_binding() == "data"
         assert tenant_binding() == "data"
         rm, rb = _resolve_placement(BUCKETS)
@@ -126,7 +126,7 @@ def battery_ambient():
         tok_in = _placement_token()
         assert tok_in != tok_out
         assert tok_in[-1] == N_DEVICES, tok_in  # device count is in the key
-        assert tok_in[2] is not None and tok_in[2] == mesh
+        assert tok_in[2] is not None and tok_in[2] == mesh.abstract_mesh
     assert _placement_token() == tok_out
     print("MESH-OK ambient")
 

@@ -246,13 +246,13 @@ def test_bucketed_under_mesh_rules_shard_map():
     scans through shard_map; with a 1-device mesh the computation is
     identical, so results must be bit-identical to the unplaced run."""
     from repro.core.bucketed import _resolve_placement
-    from repro.distributed.sharding import set_mesh, use_rules
+    from repro.distributed.sharding import auto_mesh, use_rules
 
     pk = _trace("os_scan")
     _, f_ref = compute_features(init_state(N_SLOTS), pk,
                                 backend="bucketed", buckets=4)
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
-    with set_mesh(mesh):
+    mesh = auto_mesh((jax.device_count(),), ("data",))
+    with jax.set_mesh(mesh):
         with use_rules({"flow_shards": "data"}):
             m, binding = _resolve_placement(4)
             assert m is not None and binding == "data"
@@ -261,7 +261,7 @@ def test_bucketed_under_mesh_rules_shard_map():
     np.testing.assert_array_equal(np.asarray(f), np.asarray(f_ref))
     # unplaced fallbacks: no rules bound, and a rule naming a missing axis
     assert _resolve_placement(4) == (None, None)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with use_rules({"flow_shards": "nope"}):
             assert _resolve_placement(4) == (None, None)
 
@@ -272,12 +272,12 @@ def test_fused_step_cache_keyed_on_placement():
     placement at trace time, so a cached single-device executable would
     silently keep running unplaced)."""
     from repro.serving.fused import make_fused_step
-    from repro.distributed.sharding import set_mesh, use_rules
+    from repro.distributed.sharding import auto_mesh, use_rules
 
     unplaced = make_fused_step(backend="bucketed",
                                backend_kw={"buckets": 4}, epoch=32)
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
-    with set_mesh(mesh):
+    mesh = auto_mesh((jax.device_count(),), ("data",))
+    with jax.set_mesh(mesh):
         with use_rules({"flow_shards": "data"}):
             placed = make_fused_step(backend="bucketed",
                                      backend_kw={"buckets": 4}, epoch=32)

@@ -32,7 +32,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.training import init_train_state, make_train_step
         from repro.distributed.mesh_rules import make_rules
         from repro.distributed.sharding import (use_rules, AxisRules,
-                                                named_shardings, set_mesh)
+                                                auto_mesh)
         from repro.distributed.params import param_specs, opt_specs, batch_specs
         from repro.configs.base import ShapeConfig
 
@@ -47,7 +47,7 @@ def test_sharded_train_step_matches_single_device():
         _, met0 = jax.jit(make_train_step(m, tc))(state, batch)
         ref = float(met0["loss"])
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         shp = ShapeConfig("t", 16, 8, "train")
         rules_d = make_rules(cfg, shp, multi_pod=False, model_size=4, dp_size=2)
         rules = AxisRules(rules_d)
@@ -58,10 +58,10 @@ def test_sharded_train_step_matches_single_device():
                             {"data": 2, "model": 4}, True)
             ss = {"params": ps, "opt": os_, "step": P()}
             bs = batch_specs(cfg, shp, rules)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 step = jax.jit(make_train_step(m, tc),
-                               in_shardings=named_shardings(mesh, (ss, bs)),
-                               out_shardings=named_shardings(mesh, (ss, None)))
+                               in_shardings=(ss, bs),
+                               out_shardings=(ss, None))
                 new_state, met = step(state, batch)
                 loss = float(met["loss"])
         print(json.dumps({"ref": ref, "sharded": loss}))
@@ -76,7 +76,6 @@ def test_seq_parallel_decode_matches_dense():
         import jax, jax.numpy as jnp, numpy as np, json, math
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.distributed.seq_parallel import make_seq_parallel_decode
-        from repro.distributed.sharding import set_mesh
         from repro.models.attention import decode_attention
         from repro.configs import get_arch, reduced
 
@@ -94,7 +93,7 @@ def test_seq_parallel_decode_matches_dense():
         kv_spec = P(None, "data", None, None)
         q_spec = P(None, None, None, None)
         fn = make_seq_parallel_decode(mesh, ("data",), kv_spec, q_spec)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             kc_s = jax.device_put(kc, NamedSharding(mesh, kv_spec))
             vc_s = jax.device_put(vc, NamedSharding(mesh, kv_spec))
             got = fn(q, kc_s, vc_s, cache_len)

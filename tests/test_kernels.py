@@ -116,22 +116,26 @@ def test_flash_matches_model_attention_path():
     np.testing.assert_allclose(np.asarray(d), np.asarray(pl_out), atol=2e-5)
 
 
-def test_interpret_env_read_at_call_time(monkeypatch):
-    """Regression: REPRO_PALLAS_COMPILE was read once at import time, so
-    flipping interpret/compile required a re-import.  Now the env var is
-    resolved per call, and an explicit ``interpret=`` always wins."""
-    monkeypatch.delenv("REPRO_PALLAS_COMPILE", raising=False)
-    assert ops.interpret_default() is True
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "1")
-    assert ops.interpret_default() is False
-    # explicit interpret=True overrides the compile request (CPU-safe)
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (1, 2, 32, 16))
-    k = jax.random.normal(ks[1], (1, 2, 32, 16))
-    v = jax.random.normal(ks[2], (1, 2, 32, 16))
-    out = ops.flash_attention(q, k, v, causal=True, bq=16, bk=16,
-                              interpret=True)
-    want = ref.flash_attention_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-6)
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "0")
-    assert ops.interpret_default() is True
+@pytest.mark.parametrize("interpret", [None, True, False])
+def test_interpret_rule(interpret):
+    """``interpret=None`` interprets where the computation is lowered for
+    the CPU, ``True`` always interprets, and ``False`` asks for the Mosaic
+    compile, which the CPU refuses: no kernel silently falls back to the
+    interpreter (kernels/__init__.run_pallas)."""
+    rng = np.random.default_rng(7)
+    n_slots, n = 64, 80
+    table = {f: (jnp.zeros((n_slots, 4)) - (1.0 if f == "last_t" else 0.0))
+             for f in ("last_t", "w", "ls", "ss")}
+    slots = jnp.asarray(rng.integers(0, n_slots, n), jnp.int32)
+    ts = jnp.asarray(np.sort(rng.uniform(0, 5, n)), jnp.float32)
+    lens = jnp.asarray(rng.integers(60, 1500, n), jnp.float32)
+    if interpret is False:
+        with pytest.raises(ValueError, match="interpret mode"):
+            ops.feature_update(table, slots, ts, lens, chunk=32,
+                               interpret=False)
+        return
+    _, s1 = ops.feature_update(table, slots, ts, lens, chunk=32,
+                               interpret=interpret)
+    _, s2 = ref.feature_update_ref(table, slots, ts, lens)
+    np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
+                               rtol=1e-5, atol=1e-3)

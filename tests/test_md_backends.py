@@ -115,10 +115,11 @@ def test_process_stream_chunked_equals_one_batch_pallas_md():
     np.testing.assert_array_equal(a1, a2)
 
 
-def test_kitnet_ensemble_interpret_env_read_at_call_time(monkeypatch):
-    """Regression (kernels/ops.py): the kitnet_ensemble wrapper resolves
-    interpret=None from REPRO_PALLAS_COMPILE per CALL, and an explicit
-    interpret= always wins over the environment."""
+@pytest.mark.parametrize("interpret", [None, True, False])
+def test_kitnet_ensemble_interpret_rule(interpret):
+    """kitnet_ensemble follows the kernels' interpret rule: ``None`` and
+    ``True`` interpret on the CPU (bitwise the same kernel run), and
+    ``False`` demands the Mosaic compile, which the CPU refuses."""
     from repro.kernels import ops, ref
 
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
@@ -129,16 +130,15 @@ def test_kitnet_ensemble_interpret_env_read_at_call_time(monkeypatch):
     b2 = jax.random.normal(ks[4], (3, 6)) * 0.1
     mask = (jax.random.uniform(ks[0], (3, 6)) > 0.2).astype(np.float32)
 
-    monkeypatch.delenv("REPRO_PALLAS_COMPILE", raising=False)
-    assert ops.interpret_default() is True
-    r_env = ops.kitnet_ensemble(x, w1, b1, w2, b2, mask, bb=8)
-    # flipping the env var after import must not require a re-import:
-    # explicit interpret=True stays CPU-safe while the env requests compile
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "1")
-    assert ops.interpret_default() is False
-    r_exp = ops.kitnet_ensemble(x, w1, b1, w2, b2, mask, bb=8,
-                                interpret=True)
-    np.testing.assert_array_equal(np.asarray(r_env), np.asarray(r_exp))
+    if interpret is False:
+        with pytest.raises(ValueError, match="interpret mode"):
+            ops.kitnet_ensemble(x, w1, b1, w2, b2, mask, bb=8,
+                                interpret=False)
+        return
+    r = ops.kitnet_ensemble(x, w1, b1, w2, b2, mask, bb=8,
+                            interpret=interpret)
+    r_forced = ops.kitnet_ensemble(x, w1, b1, w2, b2, mask, bb=8,
+                                   interpret=True)
+    np.testing.assert_array_equal(np.asarray(r), np.asarray(r_forced))
     want = ref.kitnet_ensemble_ref(x, w1, b1, w2, b2, mask)
-    np.testing.assert_allclose(np.asarray(r_env), np.asarray(want),
-                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(r), np.asarray(want), atol=1e-6)

@@ -21,7 +21,7 @@ def test_local_dispatch_matches_dense():
         from repro.configs import get_arch, reduced
         from repro.models import moe as moe_mod
         from repro.distributed import flags
-        from repro.distributed.sharding import use_rules, set_mesh
+        from repro.distributed.sharding import use_rules
 
         cfg = dataclasses.replace(
             reduced(get_arch("kimi-k2-1t-a32b")),
@@ -44,7 +44,7 @@ def test_local_dispatch_matches_dense():
                  "shared": {"wi": P(), "wg": P(), "wo": P()}}
         with use_rules(rules), \\
              flags.use_local_moe_dispatch(mesh, ("data",), "model"), \\
-             set_mesh(mesh):
+             jax.set_mesh(mesh):
             p_sh = jax.tree_util.tree_map(
                 lambda v, s: jax.device_put(v, NamedSharding(mesh, s)),
                 p, pspec)

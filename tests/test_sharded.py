@@ -132,13 +132,13 @@ def test_sharded_under_mesh_rules():
     """flow_shards logical-axis placement: bound rules + a 1-device mesh
     must leave results bit-identical (the constraint is layout, not math)."""
     import jax
-    from repro.distributed.sharding import set_mesh, use_rules
+    from repro.distributed.sharding import auto_mesh, use_rules
 
     pk = _trace("os_scan")
     _, f_ref = compute_features(init_state(N_SLOTS), pk, backend="serial",
                                 mode="exact")
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
-    with set_mesh(mesh):
+    mesh = auto_mesh((jax.device_count(),), ("data",))
+    with jax.set_mesh(mesh):
         with use_rules({"flow_shards": "data"}):
             _, f = compute_features(init_state(N_SLOTS), pk,
                                     backend="sharded", shards=4)
