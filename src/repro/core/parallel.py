@@ -43,6 +43,13 @@ serving step (DESIGN.md §8): flow-state updates cover every packet, but
 feature statistics are only materialised at the sampled rows.
 
 Requires ``pkts["ts"]`` sorted ascending (streams are time-ordered).
+
+Device work carries ``jax.named_scope`` labels (HLO ``op_name`` metadata
+only: no op, fusion or value changes), so a profiler trace splits FC by
+stage: ``fc.sort`` the argsorts, ``fc.scan`` the table-carry reads, decay
+preparation and segmented scans, ``fc.store`` the segment-end store-backs,
+``fc.record_gather`` the sampled-row gathers, statistics and feature
+assembly.  The serving step (serving/fused.py) wraps the pass in ``fc``.
 """
 from __future__ import annotations
 
@@ -264,47 +271,53 @@ def stream_pass(tab, stream_ids, ts, lens, n_streams, order=None,
     """
     n = stream_ids.shape[0]
     if order is None:
-        order = jnp.argsort(stream_ids, stable=True)
+        with jax.named_scope("fc.sort"):
+            order = jnp.argsort(stream_ids, stable=True)
     inv = arith.invert_perm(order)
     sid = stream_ids[order]
     t = ts[order]
     x = lens[order]
     start, end = _segments(sid)
 
-    # per-packet decay: dt to previous packet in stream (table last_t at start)
-    t_prev_in = jnp.concatenate([t[:1], t[:-1]])
-    last_t_tab = tab["last_t"][sid]                       # (n, N_DECAY)
-    fresh = last_t_tab < 0.0
-    dt = jnp.where(start[:, None],
-                   jnp.where(fresh, 0.0, t[:, None] - last_t_tab),
-                   (t - t_prev_in)[:, None])
-    dt = jnp.maximum(dt, 0.0)
-    delta = jnp.exp2(-_LAM[None, :] * dt)
-    delta = jnp.where(start[:, None] & fresh, 0.0, delta)
+    with jax.named_scope("fc.scan"):
+        # per-packet decay: dt to previous packet in stream (table last_t
+        # at start)
+        t_prev_in = jnp.concatenate([t[:1], t[:-1]])
+        last_t_tab = tab["last_t"][sid]                   # (n, N_DECAY)
+        fresh = last_t_tab < 0.0
+        dt = jnp.where(start[:, None],
+                       jnp.where(fresh, 0.0, t[:, None] - last_t_tab),
+                       (t - t_prev_in)[:, None])
+        dt = jnp.maximum(dt, 0.0)
+        delta = jnp.exp2(-_LAM[None, :] * dt)
+        delta = jnp.where(start[:, None] & fresh, 0.0, delta)
 
-    # stacked per-packet increments, table carry folded into first elements:
-    # A_1 = delta_1*A_tab + x_1
-    xs = jnp.stack([jnp.ones((n, N_DECAY)),
-                    jnp.broadcast_to(x[:, None], (n, N_DECAY)),
-                    jnp.broadcast_to((x ** 2)[:, None], (n, N_DECAY))],
-                   axis=-1)                               # (n, ND, 3)
-    tab_a = jnp.stack([tab["w"], tab["ls"], tab["ss"]], axis=-1)[sid]
-    x0 = jnp.where(start[:, None, None], xs + delta[..., None] * tab_a, xs)
-    atoms3 = seg_linear_scan(start, delta[..., None], x0,
-                             chunks=chunks, shard=shard)    # (n, ND, 3)
-    w, ls, ss = atoms3[..., 0], atoms3[..., 1], atoms3[..., 2]
+        # stacked per-packet increments, table carry folded into first
+        # elements: A_1 = delta_1*A_tab + x_1
+        xs = jnp.stack([jnp.ones((n, N_DECAY)),
+                        jnp.broadcast_to(x[:, None], (n, N_DECAY)),
+                        jnp.broadcast_to((x ** 2)[:, None], (n, N_DECAY))],
+                       axis=-1)                           # (n, ND, 3)
+        tab_a = jnp.stack([tab["w"], tab["ls"], tab["ss"]], axis=-1)[sid]
+        x0 = jnp.where(start[:, None, None], xs + delta[..., None] * tab_a,
+                       xs)
+        atoms3 = seg_linear_scan(start, delta[..., None], x0,
+                                 chunks=chunks, shard=shard)  # (n, ND, 3)
+        w, ls, ss = atoms3[..., 0], atoms3[..., 1], atoms3[..., 2]
 
     # store back last element of each segment (indices unique by construction)
-    sid_end = jnp.where(end, sid, n_streams)              # OOB drops
-    new_tab = {
-        "last_t": tab["last_t"].at[sid_end].set(
-            jnp.broadcast_to(t[:, None], (n, N_DECAY)), mode="drop"),
-        "w": tab["w"].at[sid_end].set(w, mode="drop"),
-        "ls": tab["ls"].at[sid_end].set(ls, mode="drop"),
-        "ss": tab["ss"].at[sid_end].set(ss, mode="drop"),
-    }
-    rows = inv if sample is None else inv[sample]
-    atoms = {"w": w[rows], "ls": ls[rows], "ss": ss[rows]}
+    with jax.named_scope("fc.store"):
+        sid_end = jnp.where(end, sid, n_streams)          # OOB drops
+        new_tab = {
+            "last_t": tab["last_t"].at[sid_end].set(
+                jnp.broadcast_to(t[:, None], (n, N_DECAY)), mode="drop"),
+            "w": tab["w"].at[sid_end].set(w, mode="drop"),
+            "ls": tab["ls"].at[sid_end].set(ls, mode="drop"),
+            "ss": tab["ss"].at[sid_end].set(ss, mode="drop"),
+        }
+    with jax.named_scope("fc.record_gather"):
+        rows = inv if sample is None else inv[sample]
+        atoms = {"w": w[rows], "ls": ls[rows], "ss": ss[rows]}
     return atoms, new_tab
 
 
@@ -344,7 +357,8 @@ def channel_pass(bi_k, slots, dirs, ts, lens, own_atoms, n_slots,
     """
     n = slots.shape[0]
     if order is None:
-        order = jnp.argsort(slots, stable=True)
+        with jax.named_scope("fc.sort"):
+            order = jnp.argsort(slots, stable=True)
     inv = arith.invert_perm(order)
     sid = slots[order]
     d = dirs[order]
@@ -357,45 +371,47 @@ def channel_pass(bi_k, slots, dirs, ts, lens, own_atoms, n_slots,
     own_ls = own_atoms["ls"][order]
     own_ss = own_atoms["ss"][order]
 
-    # --- residual vs own-direction mean (full width: SR consumes every row)
-    mu_own, _, _ = _stats(own_w, own_ls, own_ss)
-    lens_s = lens[order]
-    r = lens_s[:, None] - mu_own                              # (n, ND)
+    with jax.named_scope("fc.scan"):
+        # --- residual vs own-direction mean (full width: SR consumes every
+        # row)
+        mu_own, _, _ = _stats(own_w, own_ls, own_ss)
+        lens_s = lens[order]
+        r = lens_s[:, None] - mu_own                              # (n, ND)
 
-    # --- ONE latest-value scan: latest same-channel packet per direction,
-    # lanes = (w, ls, ss, residual); the table fallback is applied at
-    # emission (atoms) / consumption (residual) time ---
-    lanes = jnp.stack([own_w, own_ls, own_ss, r], axis=-1)    # (n, ND, 4)
-    latest = jnp.broadcast_to(lanes[:, None],
-                              (n, 2) + lanes.shape[1:])       # (n, 2, ND, 4)
-    per_dir = jnp.stack([d == 0, d == 1], axis=1)             # (n, 2)
-    found, val = seg_last_scan(start, per_dir, latest,
-                               chunks=chunks, shard=shard)
-    found0, found1 = found[:, 0], found[:, 1]                 # (n, 1, 1)
-    val0, val1 = val[:, 0, :, :3], val[:, 1, :, :3]           # (n, ND, 3)
-    tabv = jnp.stack([bi_k["w"], bi_k["ls"], bi_k["ss"]], axis=-1)
+        # --- ONE latest-value scan: latest same-channel packet per direction,
+        # lanes = (w, ls, ss, residual); the table fallback is applied at
+        # emission (atoms) / consumption (residual) time ---
+        lanes = jnp.stack([own_w, own_ls, own_ss, r], axis=-1)    # (n, ND, 4)
+        latest = jnp.broadcast_to(lanes[:, None],
+                                  (n, 2) + lanes.shape[1:])   # (n, 2, ND, 4)
+        per_dir = jnp.stack([d == 0, d == 1], axis=1)             # (n, 2)
+        found, val = seg_last_scan(start, per_dir, latest,
+                                   chunks=chunks, shard=shard)
+        found0, found1 = found[:, 0], found[:, 1]                 # (n, 1, 1)
+        val0, val1 = val[:, 0, :, :3], val[:, 1, :, :3]           # (n, ND, 3)
+        tabv = jnp.stack([bi_k["w"], bi_k["ls"], bi_k["ss"]], axis=-1)
 
-    def latest_res(X):
-        fnd = found[:, X, :, 0]                               # (n, 1)
-        return jnp.where(fnd, val[:, X, :, 3],
-                         bi_k["res_last"][:, X][sid])
+        def latest_res(X):
+            fnd = found[:, X, :, 0]                               # (n, 1)
+            return jnp.where(fnd, val[:, X, :, 3],
+                             bi_k["res_last"][:, X][sid])
 
-    r0 = latest_res(0)
-    r1 = latest_res(1)
-    r_opp = jnp.where((d == 0)[:, None], r1, r0)
+        r0 = latest_res(0)
+        r1 = latest_res(1)
+        r_opp = jnp.where((d == 0)[:, None], r1, r0)
 
-    # --- SR recurrence over the whole channel (both directions) ---
-    t_prev = jnp.concatenate([t[:1], t[:-1]])
-    sr_lt_tab = bi_k["sr_last_t"][sid]                        # (n, ND)
-    fresh = sr_lt_tab < 0.0
-    dt = jnp.where(start[:, None],
-                   jnp.where(fresh, 0.0, t[:, None] - sr_lt_tab),
-                   (t - t_prev)[:, None])
-    dsr = jnp.exp2(-_LAM[None, :] * jnp.maximum(dt, 0.0))
-    dsr = jnp.where(start[:, None] & fresh, 0.0, dsr)
-    x_sr = r * r_opp
-    x_sr = jnp.where(start[:, None], x_sr + dsr * bi_k["sr"][sid], x_sr)
-    sr = seg_linear_scan(start, dsr, x_sr, chunks=chunks, shard=shard)
+        # --- SR recurrence over the whole channel (both directions) ---
+        t_prev = jnp.concatenate([t[:1], t[:-1]])
+        sr_lt_tab = bi_k["sr_last_t"][sid]                        # (n, ND)
+        fresh = sr_lt_tab < 0.0
+        dt = jnp.where(start[:, None],
+                       jnp.where(fresh, 0.0, t[:, None] - sr_lt_tab),
+                       (t - t_prev)[:, None])
+        dsr = jnp.exp2(-_LAM[None, :] * jnp.maximum(dt, 0.0))
+        dsr = jnp.where(start[:, None] & fresh, 0.0, dsr)
+        x_sr = r * r_opp
+        x_sr = jnp.where(start[:, None], x_sr + dsr * bi_k["sr"][sid], x_sr)
+        sr = seg_linear_scan(start, dsr, x_sr, chunks=chunks, shard=shard)
 
     # --- bidirectional stats, emitted at the requested rows only ---
     def emit(rows):
@@ -417,24 +433,26 @@ def channel_pass(bi_k, slots, dirs, ts, lens, own_atoms, n_slots,
         return jnp.stack([ow, mu_o, sig_o, mag, rad, cov, pcc],
                          axis=-1)                             # (m, ND, 7)
 
-    feats = emit(None)[inv] if sample is None else emit(inv[sample])
+    with jax.named_scope("fc.record_gather"):
+        feats = emit(None)[inv] if sample is None else emit(inv[sample])
 
     # --- store-back (segment ends; res_last per direction: last of each) ---
-    sid_end = jnp.where(end, sid, n_slots)
-    new_bi = dict(bi_k)
-    new_bi["sr"] = bi_k["sr"].at[sid_end].set(sr, mode="drop")
-    new_bi["sr_last_t"] = bi_k["sr_last_t"].at[sid_end].set(
-        jnp.broadcast_to(t[:, None], sr.shape), mode="drop")
-    # last residual of each (channel, direction): last occurrence of the
-    # composite key sid*2+d (unique per (segment, dir) since segments are
-    # channel-contiguous) — the derived directional permutation IS the
-    # stable sort by that key, so take its segment ends (no re-sort).
-    k2s = (sid * 2 + d)[dir_gather]
-    _, end2 = _segments(k2s)
-    sid2_end = jnp.where(end2, k2s // 2, n_slots)
-    d2 = k2s % 2
-    new_bi["res_last"] = new_bi["res_last"].at[sid2_end, d2].set(
-        r[dir_gather], mode="drop")
+    with jax.named_scope("fc.store"):
+        sid_end = jnp.where(end, sid, n_slots)
+        new_bi = dict(bi_k)
+        new_bi["sr"] = bi_k["sr"].at[sid_end].set(sr, mode="drop")
+        new_bi["sr_last_t"] = bi_k["sr_last_t"].at[sid_end].set(
+            jnp.broadcast_to(t[:, None], sr.shape), mode="drop")
+        # last residual of each (channel, direction): last occurrence of the
+        # composite key sid*2+d (unique per (segment, dir) since segments are
+        # channel-contiguous) — the derived directional permutation IS the
+        # stable sort by that key, so take its segment ends (no re-sort).
+        k2s = (sid * 2 + d)[dir_gather]
+        _, end2 = _segments(k2s)
+        sid2_end = jnp.where(end2, k2s // 2, n_slots)
+        d2 = k2s % 2
+        new_bi["res_last"] = new_bi["res_last"].at[sid2_end, d2].set(
+            r[dir_gather], mode="drop")
     return feats, new_bi
 
 
@@ -450,7 +468,8 @@ def _bi_key_pass(tabs, slots, dirs, ts, lens, n_slots, sample=None,
     derived permutation.  Returns (bi features (n|m, ND, 7), updated tabs);
     ``sample`` restricts the emitted feature rows (state is always full).
     """
-    order = jnp.argsort(slots, stable=True)
+    with jax.named_scope("fc.sort"):
+        order = jnp.argsort(slots, stable=True)
     sid = slots[order]
     d_s = dirs[order]
     start, end = _segments(sid)
@@ -510,8 +529,9 @@ def _process_parallel_impl(state: Dict, pkts: Dict[str, jax.Array],
                                      sample=sample_idx, chunks=chunks,
                                      shard=shard)
     )(uni_tab, uni_ids)
-    mu, _, sig = _stats(atoms["w"], atoms["ls"], atoms["ss"])
-    uni_feats = jnp.stack([atoms["w"], mu, sig], axis=-1)    # (2, n|m, ND, 3)
+    with jax.named_scope("fc.record_gather"):
+        mu, _, sig = _stats(atoms["w"], atoms["ls"], atoms["ss"])
+        uni_feats = jnp.stack([atoms["w"], mu, sig], axis=-1)  # (2,n|m,ND,3)
 
     # ---- bidirectional: both key types vmapped, one argsort each ----
     bi_slots = jnp.stack([sl[k] for k in ("channel", "socket")])
@@ -523,9 +543,10 @@ def _process_parallel_impl(state: Dict, pkts: Dict[str, jax.Array],
                                      shard=shard)
     )(bi_tabs, bi_slots)                                     # (2, n|m, ND, 7)
 
-    out = jnp.concatenate([
-        jnp.moveaxis(uni_feats, 0, 1).reshape(n, -1),
-        jnp.moveaxis(bi_feats, 0, 1).reshape(n, -1)], axis=-1)
+    with jax.named_scope("fc.record_gather"):
+        out = jnp.concatenate([
+            jnp.moveaxis(uni_feats, 0, 1).reshape(n, -1),
+            jnp.moveaxis(bi_feats, 0, 1).reshape(n, -1)], axis=-1)
     new_state = {"uni": {**new_uni_tab, "rr": state["uni"]["rr"]},
                  "bi": {**new_bi_tabs, "rr": state["bi"]["rr"]}}
     return new_state, out

@@ -37,6 +37,7 @@ applies to the pool exactly as to the single-stream state.
 from __future__ import annotations
 
 import collections
+import functools
 import json
 import os
 import time
@@ -44,6 +45,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.core import resolve_backend
 from repro.core.state import (StatePool, slot_collisions, state_backend_of,
@@ -84,6 +86,24 @@ class DetectionEngine:
         ``from_service`` inherits both from the service's state.  Dense
         pools additionally report per-tenant ``slot_collisions`` — the
         distinct flow keys that aliased an occupied slot per chunk.
+
+    Profiler spans
+    --------------
+    While a ``jax.profiler`` trace is active the engine writes three
+    host spans (``TraceAnnotation``; with no trace active each costs one
+    disabled-annotation check):
+
+    * ``engine.dispatch`` — one fused call's set-up: popping each lane's
+      chunk, collision telemetry, stacking, the host-to-device copy and
+      the enqueue of the step.
+    * ``engine.slot_collisions`` — nested in ``engine.dispatch``: the
+      host re-hash of each lane's chunk behind the dense-pool
+      ``slot_collisions`` counter.
+    * ``engine.drain`` — blocking on the oldest in-flight batch, then the
+      result bookkeeping (records, counters, alarm logs).
+
+    The fused step's device ops carry ``jax.named_scope`` labels
+    (serving/fused.py), so a trace splits the device time by layer too.
     """
 
     def __init__(self, net, threshold: float, *, epoch: int = 1024,
@@ -267,6 +287,7 @@ class DetectionEngine:
                                 md_backend=self.md_backend, md_kw=self.md_kw,
                                 epoch=self.epoch)
 
+    @functools.partial(annotate_function, name="engine.dispatch")
     def _dispatch(self, tids: List[int], size: int) -> None:
         """Pack one chunk from each tenant in ``tids`` into a single
         tenant-batched fused call.  Returns immediately with the batch in
@@ -277,9 +298,10 @@ class DetectionEngine:
             # collide inside this chunk (host-side numpy twin of the device
             # hash, so the fused call is untouched).  Sketch pools absorb
             # collisions by design and keep the counter at zero.
-            for t, c in zip(tids, chunks):
-                self._counters[t]["slot_collisions"] += \
-                    slot_collisions(c, self.n_slots)["total"]
+            with TraceAnnotation("engine.slot_collisions"):
+                for t, c in zip(tids, chunks):
+                    self._counters[t]["slot_collisions"] += \
+                        slot_collisions(c, self.n_slots)["total"]
         pk = {k: jnp.asarray(np.stack([c[k] for c in chunks]))
               for k in chunks[0]}
         ids = jnp.asarray(np.asarray(tids, np.int32))
@@ -297,6 +319,7 @@ class DetectionEngine:
             self._t_first = t0
         self._inflight.append((tids, bases, out[1:], t0, size))
 
+    @functools.partial(annotate_function, name="engine.drain")
     def _drain_one(self) -> None:
         """Block on the OLDEST in-flight batch; only the O(records)
         sampled outputs cross to the host."""
@@ -380,7 +403,11 @@ class DetectionEngine:
     def stats(self) -> Dict:
         """Operational counters: per-tenant ingress/drop/record/alarm
         counts and p50/p99 per-chunk latency (ms), plus aggregate
-        processed-packet count and pps over the dispatch→drain window."""
+        processed-packet count and pps over the dispatch→drain window.
+
+        ``p50_ms``/``p99_ms`` run from a chunk's dispatch to its drain:
+        the time a packet waited in the tenant's ingress queue before its
+        chunk was dispatched is not in them."""
         per = {}
         for tid in self._counters:
             lat = np.asarray(self._lat[tid]) * 1e3

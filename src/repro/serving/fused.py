@@ -38,6 +38,12 @@ and the multi-tenant ``DetectionEngine`` vmaps it over a tenant axis
 (``make_tenant_step``) — T tenants' chunks gathered from a stacked state
 pool, advanced in ONE donated jit, and scattered back, tenant ids carried
 with every lane so states and epoch counters never mix.
+
+The step labels its device work with ``jax.named_scope`` (HLO ``op_name``
+metadata only): ``pool.gather`` / ``pool.scatter`` around the tenant-pool
+gather and scatter, ``fc`` around FC (with ``fc.*`` stages inside,
+core/parallel.py) and ``md.kitnet`` around scoring and the threshold
+compare, so a device trace splits the step by layer.
 """
 from __future__ import annotations
 
@@ -108,11 +114,14 @@ def _make_core(backend: str, mode: str, backend_kw: Tuple,
         # but feature rows are only materialised at the epoch boundaries —
         # sampling happens AFTER feature computation (the paper's move),
         # yet unsampled packets never pay the statistics-assembly cost
-        state, recs = compute_features_sampled(state, pkts, idx,
-                                               backend=backend, mode=mode,
-                                               **fc_kw)
-        scores = score(net, recs)
-        return state, idx, scores, scores > threshold, count
+        with jax.named_scope("fc"):
+            state, recs = compute_features_sampled(state, pkts, idx,
+                                                   backend=backend, mode=mode,
+                                                   **fc_kw)
+        with jax.named_scope("md.kitnet"):
+            scores = score(net, recs)
+            alarms = scores > threshold
+        return state, idx, scores, alarms, count
 
     return step
 
@@ -146,14 +155,16 @@ def _cached_tenant_step(backend: str, mode: str, backend_kw: Tuple,
             tree)
 
     def step(pool, tenant_ids, net, threshold, base_mods, pkts):
-        sub = jax.tree_util.tree_map(lambda x: x[tenant_ids], pool)
+        with jax.named_scope("pool.gather"):
+            sub = jax.tree_util.tree_map(lambda x: x[tenant_ids], pool)
         if lane_sharding is not None:
             sub, base_mods, pkts = (constrain(sub), constrain(base_mods),
                                     constrain(pkts))
         sub, idx, scores, alarms, counts = vcore(sub, net, threshold,
                                                  base_mods, pkts)
-        pool = jax.tree_util.tree_map(
-            lambda p, s: p.at[tenant_ids].set(s), pool, sub)
+        with jax.named_scope("pool.scatter"):
+            pool = jax.tree_util.tree_map(
+                lambda p, s: p.at[tenant_ids].set(s), pool, sub)
         return pool, idx, scores, alarms, counts
 
     return jax.jit(step, donate_argnums=(0,))

@@ -263,3 +263,84 @@ def test_serving_import_graph_stays_detection_only():
     bad = [m for m in out.stdout.split()
            if not m.startswith(allowed)]
     assert not bad, f"repro.serving pulled in disallowed modules: {bad}"
+
+
+# ---------------------------------------------------------------------------
+# profiler labels: device scopes in the fused step, host spans in the engine
+# ---------------------------------------------------------------------------
+STEP_SCOPES = ("pool.gather", "pool.scatter", "fc", "fc.sort", "fc.scan",
+               "fc.store", "fc.record_gather", "md.kitnet")
+
+
+def _path_scopes(op_name):
+    """The path components of an HLO ``op_name``, transform wrappers such
+    as ``vmap(...)`` / ``jit(...)`` removed."""
+    import re
+    return {re.sub(r"^\w+\((.*)\)$", r"\1", c) for c in op_name.split("/")}
+
+
+def test_tenant_step_hlo_names_every_scope(svc):
+    """The compiled tenant step's ``op_name`` metadata carries each of the
+    step's named scopes, so a device trace can be split by layer; and
+    every float scatter (a table store) under ``fc`` is an ``fc.store``,
+    which the trace split relies on where the TPU compiler drops a
+    scatter's metadata (bench/scopes.py)."""
+    import re
+    from repro.core.state import init_state_stacked
+    from repro.serving.fused import make_tenant_step
+    ev = _eval_trace("mirai", seed=3)
+    pk = {k: jnp.asarray(np.asarray(v)[None, :CHUNK]) for k, v in ev.items()}
+    step = make_tenant_step(epoch=EPOCH)
+    hlo = step.lower(init_state_stacked(2, N_SLOTS),
+                     jnp.asarray([1], jnp.int32), svc.net,
+                     np.float32(svc.threshold), jnp.asarray([0], jnp.int32),
+                     pk).compile().as_text()
+    found = set().union(*(_path_scopes(n) for n in
+                          re.findall(r'op_name="([^"]*)"', hlo)))
+    assert set(STEP_SCOPES) <= found, set(STEP_SCOPES) - found
+    stores = re.findall(r"= f\d+\[[^\n]* scatter\([^\n]*"
+                        r'op_name="([^"]*)"', hlo)
+    assert stores
+    for op in stores:
+        path = _path_scopes(op)
+        assert "fc.store" in path or "fc" not in path, op
+
+
+def test_engine_step_writes_nested_host_spans(svc, tmp_path):
+    """Under ``jax.profiler`` one ``step()`` writes ``engine.dispatch`` per
+    fused call with ``engine.slot_collisions`` inside it, and
+    ``engine.drain`` around the drain of the older batch."""
+    import glob
+    import os
+    ev = _eval_trace("mirai", seed=5)
+    eng = DetectionEngine.from_service(svc, n_tenants=1, chunk=CHUNK,
+                                       queue_depth=4)
+    tid = eng.add_tenant()
+    eng.submit(tid, {k: v[:CHUNK] for k, v in ev.items()})
+    eng.step()                                   # compile outside the trace
+    eng.submit(tid, {k: v[CHUNK:3 * CHUNK] for k, v in ev.items()})
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    assert eng.step() == 2
+    jax.profiler.stop_trace()
+    eng.flush()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    spans = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("engine."):
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    # two batches: two dispatches, the older batch drained in between
+    assert len(spans["engine.dispatch"]) == 2
+    assert len(spans["engine.slot_collisions"]) == 2
+    assert len(spans["engine.drain"]) >= 1
+    for (s, e), (ds, de) in zip(sorted(spans["engine.slot_collisions"]),
+                                sorted(spans["engine.dispatch"])):
+        assert ds <= s and e <= de
+    for s, e in spans["engine.drain"]:
+        assert not any(ds < e and s < de
+                       for ds, de in spans["engine.dispatch"])
