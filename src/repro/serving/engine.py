@@ -13,10 +13,11 @@ them (DESIGN.md §10):
   ONE donated jit call (``serving/fused.make_tenant_step``): the service's
   per-chunk core — FC → on-device epoch gather → KitNET → threshold —
   vmapped over the tenant axis, tenant ids carried with every lane so
-  states and per-tenant epoch counters never mix.  Per-lane results are
-  bitwise the single-tenant step's (tests/test_engine.py), so one tenant
+  states and per-tenant epoch counters never mix.  A one-lane batch is
+  bitwise the single-tenant step (tests/test_engine.py), so one tenant
   through the engine reproduces ``DetectionService.process_stream``
-  bit for bit.
+  bit for bit; in a wider batch a lane's scores can differ from its solo
+  run in the last ulp.
 * **Backpressure.**  Each tenant has a bounded ingress buffer
   (``queue_depth`` chunks); ``submit`` sheds overflow (drop-tail), never
   blocks, and the shed count is reported per tenant — the engine cannot
@@ -157,6 +158,8 @@ class DetectionEngine:
         self._t_first: Optional[float] = None
         self._t_last: Optional[float] = None
         self._pkts_done = 0
+        self._dispatches = 0
+        self._single_lane_dispatches = 0
 
     # ------------------------------------------------------------------
     # construction from a trained service
@@ -312,6 +315,8 @@ class DetectionEngine:
                                   np.float32(self.threshold), base_mods, pk)
         self.pool.stacked = out[0]
         self.pool.mark_dirty(tids)
+        self._dispatches += 1
+        self._single_lane_dispatches += len(tids) == 1
         bases = [self._pkt_count[t] for t in tids]
         for t in tids:
             self._pkt_count[t] += size
@@ -403,7 +408,9 @@ class DetectionEngine:
     def stats(self) -> Dict:
         """Operational counters: per-tenant ingress/drop/record/alarm
         counts and p50/p99 per-chunk latency (ms), plus aggregate
-        processed-packet count and pps over the dispatch→drain window.
+        processed-packet count, pps over the dispatch→drain window, and
+        the fused calls dispatched (``dispatches``), of which
+        ``single_lane_dispatches`` carried one tenant.
 
         ``p50_ms``/``p99_ms`` run from a chunk's dispatch to its drain:
         the time a packet waited in the tenant's ingress queue before its
@@ -420,7 +427,10 @@ class DetectionEngine:
         return {"tenants": per,
                 "aggregate": {"pkts_processed": self._pkts_done,
                               "wall_s": wall,
-                              "pps": self._pkts_done / wall if wall else 0.0}}
+                              "pps": self._pkts_done / wall if wall else 0.0,
+                              "dispatches": self._dispatches,
+                              "single_lane_dispatches":
+                                  self._single_lane_dispatches}}
 
     def _log_alarms(self, tid: int, gi: np.ndarray, sc: np.ndarray) -> None:
         f = self._alarm_files.get(tid)

@@ -155,16 +155,26 @@ def _cached_tenant_step(backend: str, mode: str, backend_kw: Tuple,
             tree)
 
     def step(pool, tenant_ids, net, threshold, base_mods, pkts):
+        # One unplaced lane moves by dynamic slice: a one-index gather /
+        # scatter lowers to a select over the lane's whole slot (the whole
+        # pool when it holds one tenant), where a dynamic update writes
+        # the slot in place, or compiles away when it covers the pool.
+        if lane_sharding is None and tenant_ids.shape[0] == 1:
+            take = lambda x: jax.lax.dynamic_index_in_dim(x, tenant_ids[0])
+            put = lambda p, s: jax.lax.dynamic_update_index_in_dim(
+                p, s, tenant_ids[0], 0)
+        else:
+            take = lambda x: x[tenant_ids]
+            put = lambda p, s: p.at[tenant_ids].set(s)
         with jax.named_scope("pool.gather"):
-            sub = jax.tree_util.tree_map(lambda x: x[tenant_ids], pool)
+            sub = jax.tree_util.tree_map(take, pool)
         if lane_sharding is not None:
             sub, base_mods, pkts = (constrain(sub), constrain(base_mods),
                                     constrain(pkts))
         sub, idx, scores, alarms, counts = vcore(sub, net, threshold,
                                                  base_mods, pkts)
         with jax.named_scope("pool.scatter"):
-            pool = jax.tree_util.tree_map(
-                lambda p, s: p.at[tenant_ids].set(s), pool, sub)
+            pool = jax.tree_util.tree_map(put, pool, sub)
         return pool, idx, scores, alarms, counts
 
     return jax.jit(step, donate_argnums=(0,))
@@ -208,11 +218,16 @@ def make_tenant_step(backend: str = "scan", mode: str = "exact",
     slots (traced — changing WHICH tenants ride a batch never recompiles;
     changing how MANY does), ``base_mods`` the ``(T,)`` per-tenant epoch
     residues, and ``pkts`` packet arrays stacked to ``(T, chunk)``.  Tenant
-    states are gathered from the pool, advanced independently (per-lane
-    results are bitwise those of the single-tenant step on this host —
-    tests/test_engine.py pins it), and scattered back inside the same jit,
-    so states and epoch counters cannot mix.  ``net``/``threshold`` are
-    shared: one fitted detector serving many streams.
+    states are gathered from the pool, advanced independently, and
+    scattered back inside the same jit, so states and epoch counters
+    cannot mix.  A one-lane batch (``T == 1``) with no mesh placement is
+    gathered and written back by dynamic slice, in place; wider or placed
+    batches by gather and scatter over ``tenant_ids``.  A one-lane batch
+    is bitwise the single-stream step; in a wider batch the compiler may
+    vectorise a lane's arithmetic differently, so its scores can differ
+    from its solo run in the last ulp (tests/test_engine.py).
+    ``net``/``threshold`` are shared: one fitted detector serving many
+    streams.
 
     When a mesh is bound and the ``tenants`` logical axis has a rule
     (e.g. under ``distributed.sharding.flow_mesh``), the tenant axis of

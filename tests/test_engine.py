@@ -124,6 +124,91 @@ def test_tenant_epoch_counters_never_mix(svc):
     assert not np.array_equal(out[a][1], out[b][1])
 
 
+def _gather_scatter_step():
+    """The tenant step with the pool moved by gather and scatter over the
+    id vector, as wider batches move it: the reference for one lane."""
+    from repro.serving.fused import _make_core
+    core = jax.vmap(_make_core("scan", "exact", (), "einsum", (), EPOCH),
+                    in_axes=(0, None, None, 0, 0))
+
+    def step(pool, ids, net, threshold, base_mods, pkts):
+        sub = jax.tree_util.tree_map(lambda x: x[ids], pool)
+        sub, *out = core(sub, net, threshold, base_mods, pkts)
+        return (jax.tree_util.tree_map(lambda p, s: p.at[ids].set(s),
+                                       pool, sub), *out)
+
+    return jax.jit(step)
+
+
+@pytest.mark.parametrize("n_pool,slot", [(1, 0), (4, 0), (4, 3)])
+def test_one_lane_writeback_matches_gather_scatter(svc, n_pool, slot):
+    """A one-lane batch, gathered and written back by dynamic slice, gives
+    what the gather/scatter write-back gives: equal records and alarms,
+    scores and the lane's tables within 2 ulp, every other slot
+    untouched bit for bit."""
+    from repro.core.state import init_state_stacked
+    from repro.serving.fused import make_tenant_step
+    ref_step = _gather_scatter_step()
+    thr = np.float32(svc.threshold)
+    ev = _eval_trace("mirai", seed=71)
+    lane = lambda k: {key: jnp.asarray(np.asarray(v)[None, k * CHUNK:
+                                                     (k + 1) * CHUNK])
+                      for key, v in ev.items()}
+    # distinct, non-trivial tables in every slot: one chunk per slot
+    pool = init_state_stacked(n_pool, N_SLOTS)
+    for k in range(n_pool):
+        pool = ref_step(pool, jnp.asarray([k], jnp.int32), svc.net, thr,
+                        jnp.asarray([k], jnp.int32), lane(k))[0]
+    args = (jnp.asarray([slot], jnp.int32), svc.net, thr,
+            jnp.asarray([5], jnp.int32), lane(n_pool))
+    got = make_tenant_step(epoch=EPOCH)(_copy(pool), *args)
+    want = ref_step(_copy(pool), *args)
+    idx, scores, alarms, counts = got[1:]
+    assert int(counts[0]) > 0
+    for g, w in ((idx, want[1]), (alarms, want[3]), (counts, want[4])):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    np.testing.assert_array_max_ulp(np.asarray(scores), np.asarray(want[2]),
+                                    maxulp=2)
+    others = [k for k in range(n_pool) if k != slot]
+    moved = False
+    for p0, g, w in zip(jax.tree_util.tree_leaves(pool),
+                        jax.tree_util.tree_leaves(got[0]),
+                        jax.tree_util.tree_leaves(want[0])):
+        p0, g, w = np.asarray(p0), np.asarray(g), np.asarray(w)
+        if np.issubdtype(g.dtype, np.floating):
+            np.testing.assert_array_max_ulp(g[slot], w[slot], maxulp=2)
+        else:
+            np.testing.assert_array_equal(g[slot], w[slot])
+        np.testing.assert_array_equal(g[others], p0[others])
+        moved |= not np.array_equal(g[slot], p0[slot])
+    assert moved                      # the lane's new state was written
+
+
+def test_stats_count_single_lane_dispatches(svc):
+    """``stats()["aggregate"]`` counts fused calls and those that carried
+    one tenant: every call of a one-tenant engine; fewer than all of a
+    two-tenant engine fed both streams."""
+    ev = _eval_trace("mirai", seed=73, n=160)
+    n = len(ev["ts"])
+    eng = DetectionEngine.from_service(svc, n_tenants=1, chunk=64,
+                                       queue_depth=2)
+    eng.run({eng.add_tenant(): ev})
+    agg = eng.stats()["aggregate"]
+    assert agg["dispatches"] == agg["single_lane_dispatches"] == -(-n // 64)
+
+    short = {k: v[:n - 100] for k, v in ev.items()}
+    eng = DetectionEngine.from_service(svc, n_tenants=2, chunk=64,
+                                       queue_depth=2)
+    a, b = eng.add_tenant(), eng.add_tenant()
+    eng.run({a: ev, b: short})
+    agg = eng.stats()["aggregate"]
+    single, multi = (agg["single_lane_dispatches"],
+                     agg["dispatches"] - agg["single_lane_dispatches"])
+    assert 0 < single < agg["dispatches"] and multi > 0
+    # every tenant chunk rode exactly one lane of one call
+    assert single + 2 * multi == -(-n // 64) + -(-(n - 100) // 64)
+
+
 # ---------------------------------------------------------------------------
 # backpressure
 # ---------------------------------------------------------------------------

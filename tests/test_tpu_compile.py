@@ -14,6 +14,7 @@ load the library.  Where it cannot be described, every test here skips.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,6 +101,45 @@ def test_tenant_step_compiles(one_chip, net):
             np.arange(lanes, dtype=np.int32), net, np.float32(0.5),
             np.zeros(lanes, np.int32), _pkts((lanes,)))
     _compile(step, args, one_chip)
+
+
+def _scope_ops(hlo, scope):
+    """``(opcode, instruction name, element count)`` of every instruction
+    in ``hlo`` whose ``op_name`` path holds ``scope``."""
+    ops = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%?([\w.-]+) = \w+\[([\d,]*)\]"
+                         r"\S* ([\w-]+)\(.*op_name=\"([^\"]*)\"",
+                         hlo, re.M):
+        name, dims, opcode, op_name = m.groups()
+        if scope in (re.sub(r"^\w+\((.*)\)$", r"\1", c)
+                     for c in op_name.split("/")):
+            ops.append((opcode, name,
+                        int(np.prod([int(d) for d in dims.split(",") if d]))))
+    return ops
+
+
+@pytest.mark.parametrize("pool_slots", [1, 4])
+def test_one_lane_tenant_step_writes_back_in_place(one_chip, net,
+                                                   pool_slots):
+    """A one-lane batch writes its tables back by dynamic update: no
+    table-sized select or scatter under ``pool.scatter`` (a one-index
+    scatter lowers to a select over the lane's whole slot, which with one
+    slot rewrites the whole pool every step); with more slots than lanes
+    the write-back is a ``dynamic-update-slice``."""
+    from repro.serving.fused import make_tenant_step
+    step = make_tenant_step(epoch=EPOCH)
+    args = (jax.eval_shape(lambda: init_state_stacked(pool_slots, N_SLOTS)),
+            np.zeros(1, np.int32), net, np.float32(0.5),
+            np.zeros(1, np.int32), _pkts((1,)))
+    hlo = step.lower(*_shapes(args, one_chip)).compile().as_text()
+    ops = _scope_ops(hlo, "pool.scatter")
+    bad = [(op, name) for op, name, size in ops if size >= N_SLOTS
+           and (op in ("select", "scatter") or "select" in name
+                or "scatter" in name)]
+    assert not bad, bad
+    if pool_slots > 1:
+        assert any(op == "dynamic-update-slice" and size >= N_SLOTS
+                   for op, _, size in ops), ops
 
 
 def test_feature_update_full_compiles(one_chip):
