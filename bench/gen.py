@@ -1,17 +1,15 @@
 """One general traffic generator, driven by a mix file (``traffic/<mix>.json``).
 
-A mix gives the parameters; this module turns them into per-tenant packet
-pools with vectorised numpy, from the seed alone.  A pool is replayed in
-laps: packet ``i`` of a tenant's stream is pool entry ``i % P`` with its
-timestamp advanced by ``(i // P) * span``.  One shape exists:
+A mix gives the parameters; its ``shape`` names the file
+``shapes/<shape>.py`` whose ``pools(mix, n_tenants, rng)`` turns them into
+per-tenant packet pools with vectorised numpy, from the seed alone.  A pool
+is replayed in laps: packet ``i`` of a tenant's stream is pool entry
+``i % P`` with its timestamp advanced by ``(i // P) * span``.
 
-* ``backbone``: one stream of many concurrent 5-tuple flows whose packet
-  shares follow Zipf(``zipf_s``), IMIX packet sizes, and timestamps spaced
-  by each packet's wire time at the mix's nominal ``link_bps``.
-
-It mixes in ``attack_share`` of packets from the attack families named in
-``attacks`` (shapes copied from the published attack descriptions the
-repository's synthetic traces follow).
+This module keeps what shapes share: ``Pool``, and the attack families
+(shapes copied from the published attack descriptions the repository's
+synthetic traces follow) that ``mix_in_attacks`` writes over a share of a
+pool's packets.
 """
 from __future__ import annotations
 
@@ -19,6 +17,8 @@ from dataclasses import dataclass
 from typing import Callable, Dict, List
 
 import numpy as np
+
+from bench import spec
 
 TCP, UDP = 6, 17
 FIELDS = ("src", "dst", "sport", "dport", "proto")
@@ -145,8 +145,8 @@ ATTACKS: Dict[str, Callable] = {
 }
 
 
-def _mix_in_attacks(pk: Dict[str, np.ndarray], share: float,
-                    families: List[str], rng, lan: int, wan: int) -> np.ndarray:
+def mix_in_attacks(pk: Dict[str, np.ndarray], share: float,
+                   families: List[str], rng, lan: int, wan: int) -> np.ndarray:
     """Overwrite ``share`` of the positions with attack packets, split
     evenly over ``families``; returns the label vector."""
     n = pk["length"].shape[0]
@@ -166,48 +166,7 @@ def _mix_in_attacks(pk: Dict[str, np.ndarray], share: float,
     return label
 
 
-# ---------------------------------------------------------------------------
-# backbone: Zipf flows, IMIX sizes, wire-time spacing at the nominal rate
-# ---------------------------------------------------------------------------
-def _backbone(mix: Dict, rng) -> Pool:
-    P, F = int(mix["pool_packets"]), int(mix["flows"])
-    w = 1.0 / np.arange(1, F + 1, dtype=np.float64) ** float(mix["zipf_s"])
-    cdf = np.cumsum(w)
-    cdf /= cdf[-1]
-    rank = np.searchsorted(cdf, rng.random(P), side="right").clip(0, F - 1)
-    flow = rng.permutation(F)[rank]          # popularity not tied to flow id
-    lan, wan = int(mix["src_base"]), int(mix["dst_base"])
-    f_src = (lan + rng.integers(0, int(mix["src_hosts"]), F)).astype(np.uint32)
-    f_dst = (wan + rng.integers(0, int(mix["dst_hosts"]), F)).astype(np.uint32)
-    f_sport = rng.integers(1024, 65536, F).astype(np.uint32)
-    ports = np.asarray(mix["server_ports"], np.uint32)
-    f_dport = ports[rng.integers(0, len(ports), F)]
-    f_proto = np.where(rng.random(F) < float(mix["udp_share"]), UDP, TCP) \
-        .astype(np.uint32)
-    rev = rng.random(P) < float(mix["reverse_share"])
-    s, d = f_src[flow], f_dst[flow]
-    sp, dp = f_sport[flow], f_dport[flow]
-    pk = {"src": np.where(rev, d, s), "dst": np.where(rev, s, d),
-          "sport": np.where(rev, dp, sp), "dport": np.where(rev, sp, dp),
-          "proto": f_proto[flow].copy()}
-    sizes = np.asarray(mix["sizes"], np.float64)
-    wts = np.asarray(mix["size_weights"], np.float64)
-    pk["length"] = sizes[rng.choice(len(sizes), size=P, p=wts / wts.sum())]
-    label = _mix_in_attacks(pk, float(mix["attack_share"]),
-                            list(mix["attacks"]), rng, lan, wan)
-    length = pk.pop("length").astype(np.float32)
-    wire = length.astype(np.float64) * 8.0 / float(mix["link_bps"])
-    ts = np.concatenate([[0.0], np.cumsum(wire[:-1])])
-    return Pool(fields=pk, length=length, label=label, ts_base=ts,
-                span=float(ts[-1] + wire[-1]))
-
-
 def pools(mix: Dict, n_tenants: int, seed: int) -> List[Pool]:
     """Every tenant's pool for this mix and seed."""
     rng = np.random.default_rng(np.random.SeedSequence([seed, 0x5EED]))
-    shape = mix["shape"]
-    if shape == "backbone":
-        if n_tenants != 1:
-            raise ValueError("the backbone shape is one stream")
-        return [_backbone(mix, rng)]
-    raise ValueError(f"unknown traffic shape {shape!r}")
+    return spec.shape(mix["shape"])(mix, n_tenants, rng)

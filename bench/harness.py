@@ -3,8 +3,8 @@
 Set-up makes every tenant's traffic pool from the seed, fits KitNET's
 normalisers and threshold with the plain reference on the first records
 (``weights.py``), builds ``DetectionEngine`` with the program's default FC
-and MD backends, and warms every lane count the window can batch (1 ..
-``max_batch``) through ``submit`` -> ``step``.  The window then drives the
+and MD backends, and warms every lane count the window batches
+(``lane_counts``) through ``submit`` -> ``step``.  The window then drives the
 same public entry with a saturating feed: a closed loop on ingress
 ``room``, whole chunks submitted whenever a tenant has room, so the engine
 is never short of work and nothing is shed.  ``pps`` is packets drained in
@@ -15,6 +15,10 @@ of the chunks submitted in the window has come back; then the peak device
 memory is read, the engine is dropped, and every record returned is
 compared with the plain reference (``reference.py``) over the same stream:
 its indices, its score and its alarm, tenant by tenant.
+
+A cell on more than one chip runs from the engine's build through the
+collection of its results under its configuration's ``placement``, bound
+by ``flow_mesh`` over exactly the cell's chips (``placed``).
 """
 from __future__ import annotations
 
@@ -26,7 +30,7 @@ import sys
 import tempfile
 import time
 from dataclasses import dataclass
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -62,6 +66,30 @@ def enable_compile_cache() -> str:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path
+
+
+def lane_counts(tenants: int, max_batch: int) -> List[int]:
+    """The lane counts of the batches a saturating feed makes: every tenant
+    has a chunk ready at each step, so the engine dispatches full groups of
+    ``max_batch`` and the remainder."""
+    full, rest = divmod(tenants, max_batch)
+    return ([max_batch] if full else []) + ([rest] if rest else [])
+
+
+@contextlib.contextmanager
+def placed(cell: spec.Cell, devs: Sequence):
+    """The cell's placement bound over a mesh of exactly ``devs``; nothing
+    is bound for one chip."""
+    if cell.chips == 1:
+        yield
+        return
+    from repro.distributed.sharding import flow_mesh
+    with flow_mesh(cell.chips, axis=spec.MESH_AXIS,
+                   rules=cell.config["placement"]) as mesh:
+        if list(mesh.devices.flat) != list(devs):
+            raise RuntimeError(f"the mesh holds {list(mesh.devices.flat)}, "
+                               f"the cell runs on {list(devs)}")
+        yield
 
 
 @dataclass
@@ -116,9 +144,9 @@ class Run:
         for t in self.tenants:
             if self.engine.add_tenant() != t.tid:
                 raise RuntimeError("tenant ids must be 0..T-1 in order")
-        # warm every lane count: L tenants ready, one step, L = 1..max_batch
+        # warm each lane count the window batches: L tenants ready, one step
         chunk = int(cfg["chunk"])
-        for lanes in range(1, int(cfg["max_batch"]) + 1):
+        for lanes in lane_counts(T, int(cfg["max_batch"])):
             for t in self.tenants[:lanes]:
                 self.offer(t, chunk)
             self.step()
@@ -277,9 +305,10 @@ class Run:
                 "records_missing": missing, "unanswered": self.unanswered,
                 "compared": compared, "reference_alarms": alarms}
 
-    def reduce_trace(self, peak: Dict) -> None:
-        """Per-layer inputs from the traced slice."""
-        red = tracing.reduce(tracing.load(self.trace_dir))
+    def reduce_trace(self, peak: Dict, devs: Sequence) -> None:
+        """Per-layer inputs from the traced slice on the cell's chips."""
+        red = tracing.reduce(tracing.load(self.trace_dir),
+                             [d.id for d in devs])
         shutil.rmtree(self.trace_dir, ignore_errors=True)
         batches, d0, d1 = self.traced
         chunk, epoch = int(self.cfg["chunk"]), int(self.cfg["epoch"])
@@ -318,12 +347,13 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     peak = device.peaks(kind)
     enable_compile_cache()
     r = Run(cell, seed, trace)
-    r.setup()
-    r.m["setup_s"] = time.perf_counter() - t_start
-    r.window(seconds)
-    r.collect(devs)
+    with placed(cell, devs):
+        r.setup()
+        r.m["setup_s"] = time.perf_counter() - t_start
+        r.window(seconds)
+        r.collect(devs)
     if trace:
-        r.reduce_trace(peak)
+        r.reduce_trace(peak, devs)
     checks = r.check()
     correct, limits = judge(checks, cell.config)
     metrics = {}
@@ -348,8 +378,11 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
             "reference_alarms": checks["reference_alarms"],
             "window_s": r.m["window_s"],
             "compiles_in_window": r.m["compiles_in_window"]}
+    if trace:
+        info["busy_s_per_device"] = red.busy_s_per_device
     print("info " + " ".join(f"{k}={v}" for k, v in info.items()),
           file=sys.stderr)
+    out["info"] = info
     for k in limits:
         print(f"check {k} {checks[k]!r} limit {limits[k]!r}", file=sys.stderr)
     out["checks"] = {k: {"value": checks[k], "limit": limits[k]}

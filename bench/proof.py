@@ -68,12 +68,14 @@ def main() -> int:
          for s in _ints(args.fault_seeds)]
     for seed, k in runs:
         t0 = time.perf_counter()
-        with threshold_scaled(k) if k != 1.0 else contextlib.nullcontext():
-            r = harness.Run(cell, seed, False)
-            r.setup()
-        r.m["setup_s"] = time.perf_counter() - t0
-        r.window(args.seconds)
-        r.collect(devs)
+        with harness.placed(cell, devs):
+            with threshold_scaled(k) if k != 1.0 else \
+                    contextlib.nullcontext():
+                r = harness.Run(cell, seed, False)
+                r.setup()
+            r.m["setup_s"] = time.perf_counter() - t0
+            r.window(args.seconds)
+            r.collect(devs)
         t1 = time.perf_counter()
         line = {"seed": seed, "setup_s": r.m["setup_s"],
                 "window_s": r.m["window_s"]}

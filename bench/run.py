@@ -7,8 +7,10 @@ The cell is an entry of ``BENCHMARK.json``'s ``workloads``.  The last line
 of standard output is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics, or with
 ``--trace 1`` its per-layer metrics), ``device``, with ``--trace 1`` a
-``breakdown``, and last ``checks``: each number compared with the plain
-reference beside its limit (also the last lines of standard error).
+``breakdown``, ``info`` (records compared, the window's seconds, compiles
+inside it, with ``--trace 1`` each chip's busy seconds), and last
+``checks``: each number compared with the plain reference beside its limit
+(also the last lines of standard error).
 
 A run that finds no TPU, or fewer chips than the cell asks for, exits 3
 and prints no result.

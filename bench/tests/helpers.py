@@ -1,8 +1,9 @@
 """A small cell for CPU tests: the configuration and mix cut to sizes the
-CPU runs in seconds, and a stand-in for the chip look."""
+CPU runs in seconds, and stand-ins for the chip look and for the device
+planes a CPU profile lacks."""
 from __future__ import annotations
 
-from bench import device, harness, spec
+from bench import device, harness, spec, tracing
 
 E2E = [("pps", "pkt/s"), ("setup_s", "s")]
 
@@ -28,3 +29,24 @@ def on_cpu(monkeypatch) -> None:
     monkeypatch.setattr(device, "chips", lambda n: jax.devices()[:n])
     monkeypatch.setattr(device, "peaks", lambda kind: peaks("TPU v5 lite"))
     monkeypatch.setattr(harness, "enable_compile_cache", lambda: None)
+
+
+def device_planes(monkeypatch, idle: int = 1) -> None:
+    """Let a traced run read device planes on the CPU, whose profile has
+    none: every JAX device is busy over each ``engine.step`` span of the
+    traced slice, and ``idle`` planes of devices beyond them hold nothing."""
+    import jax
+    load = tracing.load
+
+    def with_planes(logdir):
+        tr = load(logdir)
+        steps = [("fusion.0 f32[1]", s, d) for n, s, d in tr.spans
+                 if n == "engine.step"]
+        ids = [d.id for d in jax.devices()]
+        for i in ids:
+            tr.ops[tracing.plane(i)] = list(steps)
+        for i in range(max(ids) + 1, max(ids) + 1 + idle):
+            tr.ops[tracing.plane(i)] = []
+        return tr
+
+    monkeypatch.setattr(tracing, "load", with_planes)

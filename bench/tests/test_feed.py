@@ -10,13 +10,18 @@ from bench.tests.helpers import small_cell
 
 
 class FakeEngine:
-    """The engine's public contract with a fixed cost per step."""
+    """The engine's public contract with a fixed cost per step: a step
+    dispatches every ready tenant's chunks in batches of at most
+    ``max_batch`` lanes, until no tenant has a chunk ready."""
 
-    def __init__(self, tenants, chunk, depth, epoch, step_s=0.0):
+    def __init__(self, tenants, chunk, depth, epoch, step_s=0.0,
+                 max_batch=1):
         self.chunk, self.depth, self.epoch, self.step_s = (chunk, depth,
                                                            epoch, step_s)
+        self.max_batch = max_batch
         self.buf = {t: 0 for t in range(tenants)}
         self.done = {t: 0 for t in range(tenants)}
+        self.lanes = set()                    # lane counts dispatched
 
     def room(self, t):
         return self.depth * self.chunk - self.buf[t]
@@ -27,14 +32,21 @@ class FakeEngine:
         return take
 
     def step(self):
-        ready = [t for t in self.buf if self.buf[t] >= self.chunk]
-        for t in ready:
-            while self.buf[t] >= self.chunk:
-                self.buf[t] -= self.chunk
-                self.done[t] += self.chunk
-        if ready and self.step_s:
+        batches = 0
+        while True:
+            ready = [t for t in self.buf if self.buf[t] >= self.chunk]
+            if not ready:
+                break
+            for i in range(0, len(ready), self.max_batch):
+                lanes = ready[i:i + self.max_batch]
+                self.lanes.add(len(lanes))
+                for t in lanes:
+                    self.buf[t] -= self.chunk
+                    self.done[t] += self.chunk
+                batches += 1
+        if batches and self.step_s:
             time.sleep(self.step_s)
-        return 1 if ready else 0
+        return batches
 
     def results(self, t):
         n = self.done[t] // self.epoch
@@ -82,3 +94,19 @@ def test_an_engine_that_drops_offered_packets_stops_the_run():
     r.engine.submit = lambda t, pkts: len(pkts["ts"]) - 1
     with pytest.raises(RuntimeError):
         r.window(0.2)
+
+
+@pytest.mark.parametrize("tenants,max_batch,lanes", [
+    (1, 1, [1]), (4, 4, [4]), (16, 16, [16]), (6, 4, [4, 2]), (2, 4, [2])])
+def test_warm_up_compiles_the_lane_counts_the_feed_dispatches(
+        tenants, max_batch, lanes):
+    assert harness.lane_counts(tenants, max_batch) == lanes
+    cell = small_cell()
+    cfg = cell.config
+    r = harness.Run(cell, 11, False)
+    pool = gen.pools(cell.mix, 1, 11)[0]
+    r.tenants = [harness.Tenant(t, pool, None, None) for t in range(tenants)]
+    r.engine = FakeEngine(tenants, cfg["chunk"], cfg["queue_depth"],
+                          cfg["epoch"], max_batch=max_batch)
+    r.window(0.2)
+    assert sorted(r.engine.lanes) == sorted(lanes)

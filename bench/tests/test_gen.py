@@ -1,5 +1,7 @@
 """The traffic generator: deterministic per seed, Zipf and IMIX shares as
 the mix states, pools replayed in laps."""
+import hashlib
+
 import numpy as np
 import pytest
 
@@ -68,3 +70,28 @@ def test_slices_replay_the_pool_in_laps():
     due = np.r_[p.ts_base[-3:], p.ts_base[:3] + p.span]
     assert np.array_equal(s["ts"], due.astype(np.float32))
     assert p.ts_base[0] == 0.0 and p.span > p.ts_base[-1]
+
+
+def _digest(p):
+    h = hashlib.sha256()
+    for f in gen.FIELDS:
+        h.update(p.fields[f].tobytes())
+    for a in (p.length, p.label, p.ts_base):
+        h.update(a.tobytes())
+    h.update(np.float64(p.span).tobytes())
+    return h.hexdigest()
+
+
+# digests of the pools the generator drew when the backbone shape was code
+# of gen.py: the shape's file draws them bit for bit
+@pytest.mark.parametrize("over,seed,digest", [
+    (dict(pool_packets=200_000, flows=8192), 2**31 + 12345,
+     "bbe889c3b58914ed31f4b70eb11ab19feabd309e413db193a2ed18226810792b"),
+    (dict(pool_packets=200_000, flows=8192), 0,
+     "99fcf83a1ed460de9a6a45cc1bf6a292bf5e8c36cf379b5ddcd7b86f064d2e04"),
+    ({}, 2**31 + 777,
+     "a4e98fa5bcec30bcd88241dceb88dff25839984f9ed241219704a692d3add305"),
+])
+def test_backbone_shape_draws_the_pools_it_always_drew(over, seed, digest):
+    m = dict(spec.mix("backbone_zipf"), **over)
+    assert _digest(gen.pools(m, 1, seed)[0]) == digest

@@ -159,7 +159,7 @@ def _trace():
 def test_split_layers_add_up_to_the_step():
     tr = _trace()
     sp = scopes.split(tr, scopes.op_scopes(HLO), steps=2)
-    red = tracing.reduce(tr)
+    red = tracing.reduce(tr, [0])
     step_ms = spec.reader("step_device_ms.sat")(
         {"trace": red, "batches_traced": 2})
     layer = sp.layer_ms()
@@ -201,7 +201,7 @@ def test_existing_readers_unchanged_by_program_spans():
                                 if s[0] in tracing.HOST_SPANS])
     read = {}
     for t in (tr, bare):
-        m = {"trace": tracing.reduce(t), "batches_traced": 2,
+        m = {"trace": tracing.reduce(t, [0]), "batches_traced": 2,
              "least_s_traced": 50e-9}
         read[id(t)] = {n: spec.reader(n)(m) for n in (
             "device_idle_frac.sat", "step_device_ms.sat",

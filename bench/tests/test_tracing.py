@@ -24,9 +24,10 @@ def test_union_and_busy():
 
 
 def test_reduce_shares_and_gaps():
-    red = tracing.reduce(_trace())
+    red = tracing.reduce(_trace(), [0])
     assert red.window_s == pytest.approx(1e-6)
     assert red.busy_s == pytest.approx(350e-9)
+    assert red.busy_s_per_device == [pytest.approx(350e-9)]
     # sort.3 covers 50..150, sort.7 covers 900..1000
     assert red.sort_s == pytest.approx(200e-9)
     assert dict(red.device_ops) == pytest.approx(
@@ -40,13 +41,42 @@ def test_reduce_shares_and_gaps():
 def test_reduce_averages_over_devices():
     tr = _trace()
     tr.ops["/device:TPU:1"] = [("fusion.9", 0, 1000)]
-    red = tracing.reduce(tr)
+    red = tracing.reduce(tr, [0, 1])
     assert red.busy_s == pytest.approx((350 + 1000) / 2 * 1e-9)
+    assert red.busy_s_per_device == [pytest.approx(350e-9),
+                                     pytest.approx(1000e-9)]
+    assert red.op_s["fusion.9"] == pytest.approx(500e-9)
+
+
+def test_reduce_reads_only_the_cells_devices():
+    tr = _trace()
+    alone = tracing.reduce(tr, [0])
+    tr.ops["/device:TPU:1"] = []                         # idle, not the cell's
+    tr.ops["/device:TPU:5"] = [("fusion.9", 0, 1000)]    # busy, not the cell's
+    assert tracing.reduce(tr, [0]) == alone
+    # a cell on the devices JAX numbers 5 and 0 reads those planes
+    both = tracing.reduce(tr, [5, 0])
+    assert both.busy_s_per_device == [pytest.approx(1000e-9),
+                                      pytest.approx(350e-9)]
+    with pytest.raises(RuntimeError):
+        tracing.reduce(tr, [0, 2])                       # no plane of 2
+
+
+def test_op_s_keeps_every_op_and_sums_to_their_time():
+    ops = [(f"fusion.{i}", 100 * i, 50) for i in range(12)] + \
+        [("fusion.0", 1150, 100)]                           # clipped to 50
+    tr = tracing.Trace(window=(0, 1200), ops={"/device:TPU:0": ops})
+    red = tracing.reduce(tr, [0])
+    assert len(red.op_s) == 12 and len(red.device_ops) == 10
+    assert sum(red.op_s.values()) == pytest.approx(12 * 50e-9 + 50e-9)
+    assert red.op_s["fusion.0"] == pytest.approx(100e-9)
+    assert red.device_ops[0] == ("fusion.0", red.op_s["fusion.0"])
+    assert dict(red.device_ops).items() <= red.op_s.items()
 
 
 def test_no_device_plane_raises():
     with pytest.raises(RuntimeError):
-        tracing.reduce(tracing.Trace(window=(0, 1), ops={}, spans=[]))
+        tracing.reduce(tracing.Trace(window=(0, 1), ops={}, spans=[]), [0])
 
 
 def _read(metric, m):
@@ -54,7 +84,7 @@ def _read(metric, m):
 
 
 def test_readers_from_reduction():
-    red = tracing.reduce(_trace())
+    red = tracing.reduce(_trace(), [0])
     m = {"trace": red, "batches_traced": 5, "least_s_traced": 35e-9}
     assert _read("device_idle_frac.sat", m) == pytest.approx(65.0)
     assert _read("step_device_ms.sat", m) == pytest.approx(350e-9 * 1e3 / 5)

@@ -4,7 +4,8 @@ The benchmark writes its own host spans (``jax.profiler.TraceAnnotation``)
 around its calls into the engine: ``bench.window`` around the traced
 slice, ``bench.generate``, ``engine.submit`` and ``engine.step`` inside
 it.  The device's operations come from the trace's device planes
-(``/device:TPU:<n>``, line ``XLA Ops``).  Everything here works on plain
+(``/device:TPU:<id>``, line ``XLA Ops``); the reduction reads only the
+planes of the devices the cell ran on.  Everything here works on plain
 ``(name, start_ns, duration_ns)`` tuples, so it is checked on synthesised
 traces without a chip.
 """
@@ -118,27 +119,39 @@ def name_gap(gap: Tuple[float, float], spans: Sequence[Event]) -> str:
     return name
 
 
+def plane(device_id: int) -> str:
+    """The trace's plane of the device JAX numbers ``device_id``."""
+    return f"/device:TPU:{device_id}"
+
+
 @dataclass
 class Reduced:
     window_s: float
     busy_s: float                       # mean over the devices used
+    busy_s_per_device: List[float]      # in the order the devices were given
     sort_s: float
-    device_ops: List[Tuple[str, float]]       # top 10 by total seconds
+    op_s: Dict[str, float]              # every op, mean over the devices used
+    device_ops: List[Tuple[str, float]]       # top 10 of op_s
     idle_gaps: List[Tuple[str, float]]        # 10 longest
 
 
-def reduce(tr: Trace) -> Reduced:
+def reduce(tr: Trace, device_ids: Sequence[int]) -> Reduced:
+    """The numbers of the traced slice on the devices the cell ran on;
+    other devices' planes are left out."""
     lo, hi = tr.window
-    names = sorted(tr.ops)
+    names = [plane(i) for i in device_ids]
     if not names:
-        raise RuntimeError("the trace holds no device plane")
-    busy = sort = 0.0
+        raise RuntimeError("no device to read the trace of")
+    missing = [n for n in names if n not in tr.ops]
+    if missing:
+        raise RuntimeError(f"the trace holds no plane {', '.join(missing)}")
+    busy, sort = [], 0.0
     per_op: Dict[str, float] = {}
     all_gaps: List[Tuple[str, float]] = []
     for dev in names:
-        ev = tr.ops.get(dev, [])
+        ev = tr.ops[dev]
         b = union(ev, lo, hi)
-        busy += sum(e - s for s, e in b)
+        busy.append(sum(e - s for s, e in b))
         sort += busy_ns([x for x in ev if is_sort(x[0])], lo, hi)
         for n, s, d in ev:
             ov = min(hi, s + d) - max(lo, s)
@@ -147,9 +160,10 @@ def reduce(tr: Trace) -> Reduced:
         all_gaps += [(name_gap(g, tr.spans), g[1] - g[0])
                      for g in gaps(b, lo, hi)]
     k = len(names)
-    top = sorted(per_op.items(), key=lambda kv: -kv[1])[:10]
+    op_s = {n: v / k / 1e9 for n, v in per_op.items()}
+    top = sorted(op_s.items(), key=lambda kv: -kv[1])[:10]
     longest = sorted(all_gaps, key=lambda kv: -kv[1])[:10]
-    return Reduced(window_s=(hi - lo) / 1e9, busy_s=busy / k / 1e9,
-                   sort_s=sort / k / 1e9,
-                   device_ops=[(n, v / k / 1e9) for n, v in top],
+    return Reduced(window_s=(hi - lo) / 1e9, busy_s=sum(busy) / k / 1e9,
+                   busy_s_per_device=[b / 1e9 for b in busy],
+                   sort_s=sort / k / 1e9, op_s=op_s, device_ops=top,
                    idle_gaps=[(n, v / 1e9) for n, v in longest])
